@@ -26,10 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ompi_tpu.mesh import AXIS

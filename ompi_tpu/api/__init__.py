@@ -40,6 +40,9 @@ def init(mca_params: dict[str, str] | None = None) -> Comm:
         return _world
     # MPI_DOUBLE / 64-bit ints are first-class datatypes.
     jax.config.update("jax_enable_x64", True)
+    from ompi_tpu import compile_cache
+
+    compile_cache.enable()
     from ompi_tpu.core import hooks, output
 
     hooks.fire("mpi_init_top")
@@ -124,8 +127,11 @@ def init(mca_params: dict[str, str] | None = None) -> Comm:
     # that bypass interpreter shutdown hooks
     _register_crash_flush()
     _initialized = True
-    output.verbose(1, "runtime", "MPI_Init complete: world size %d (%s)",
-                   _world.size, type(_world).__name__)
+    dev = wm.devices[0]
+    output.verbose(1, "runtime",
+                   "MPI_Init complete: world size %d (%s) on %d x %s "
+                   "(platform %s)", _world.size, type(_world).__name__,
+                   wm.size, dev.device_kind, dev.platform)
     hooks.fire("mpi_init_bottom", world=_world)
     return _world
 

@@ -46,6 +46,9 @@ ENV_RSH = "OMPI_TPU_RSH"
 #: host map exists): detector groups and the sharded modex partition
 #: by real host instead of ft_group_size chunks
 ENV_HOST_IDS = "OMPI_TPU_HOST_IDS"
+#: "local rank,ranks on the host" of a rank launched on a remote host:
+#: ompi_tpu.boot.bind works out its chip there, not on the launcher
+ENV_HOST_SLOT = "OMPI_TPU_HOST_SLOT"
 
 
 def respawn_timeout(store) -> float:

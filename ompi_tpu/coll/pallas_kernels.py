@@ -52,12 +52,11 @@ __all__ = [
 
 @functools.lru_cache(maxsize=1)
 def dma_available() -> bool:
-    """True when a real TPU backend is attached — the only platform
-    the async-remote-copy DMA leg lowers on."""
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except Exception:  # noqa: BLE001 — no backend at all
-        return False
+    """True when the default backend is a TPU — the only platform the
+    async-remote-copy DMA leg lowers on.  A backend that fails to
+    initialize raises here: on the chip, a swallowed error would
+    quietly swap the DMA kernel for a ``ppermute``."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def _interpret_forced() -> bool:
@@ -97,11 +96,29 @@ def _remote_hop_kernel(x_ref, o_ref, send_sem, recv_sem, *, n: int):
     HBM→HBM copy toward the right neighbor, then wait BOTH semaphores
     — send (our buffer is reusable) and recv (the left neighbor's
     bytes have landed).  The send/recv semaphore pair is the exact
-    protocol the DCN device plane maps RTS/CTS onto."""
+    protocol the DCN device plane maps RTS/CTS onto.
+
+    The copy lands in the right neighbor's ``o_ref``, whose HBM XLA
+    may have assigned from a buffer an earlier op on that device still
+    reads.  So the hop opens with a one-way neighbor barrier: each
+    device tells its LEFT neighbor (the one that writes into it) that
+    it has entered this hop, and waits for the same word from its
+    RIGHT neighbor before it starts the copy.  One signal per device
+    per hop keeps the count exact across the hops of a schedule: the
+    right neighbor can enter hop k+1 only after our hop-k copy into it
+    completed, so our hop-k wait never consumes a hop-k+1 signal, and
+    a signal that arrives early is consumed by the next hop's wait
+    (every device runs the same sequence of hop kernels)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    my_id = lax.axis_index(AXIS)
-    right = lax.rem(my_id + 1, n)
+    my_id = lax.axis_index(AXIS)  # int32, also under jax_enable_x64
+    n32 = jnp.int32(n)
+    right = lax.rem(my_id + 1, n32)
+    left = lax.rem(my_id + n32 - 1, n32)
+    barrier = pltpu.get_barrier_semaphore()
+    pltpu.semaphore_signal(barrier, 1, device_id=(left,),
+                           device_id_type=pltpu.DeviceIdType.MESH)
+    pltpu.semaphore_wait(barrier, 1)
     copy = pltpu.make_async_remote_copy(
         src_ref=x_ref,
         dst_ref=o_ref,
@@ -115,20 +132,23 @@ def _remote_hop_kernel(x_ref, o_ref, send_sem, recv_sem, *, n: int):
 
 
 def _dma_hop(x, n: int):
-    """TPU leg: the pallas_call wrapping one remote-copy hop."""
+    """TPU leg: the pallas_call wrapping one remote-copy hop.  Every
+    hop kernel shares ``collective_id`` 0: they all synchronize with
+    the same ring neighbors on the one mesh axis."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
     )
     return pl.pallas_call(
         functools.partial(_remote_hop_kernel, n=n),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(collective_id=0),
     )(x)
 
 

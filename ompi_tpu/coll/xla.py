@@ -34,10 +34,7 @@ from typing import Any, Callable
 
 import jax
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5: experimental namespace (same signature)
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ompi_tpu.core.registry import Component, register_component
@@ -119,28 +116,15 @@ class XlaCollModule(CollModule):
 
         ``pallas=True`` disables shard_map's replication checking —
         ``pallas_call`` has no replication rule, so the Pallas ring
-        family cannot trace under it (the kwarg name drifted across
-        jax versions: check_rep → check_vma; detect, don't guess)."""
+        family cannot trace under it."""
         mesh = self.comm.mesh.mesh
         specs = [P(AXIS)] * nin
-        kwargs = {}
-        if pallas:
-            import inspect
-
-            try:
-                params = inspect.signature(shard_map).parameters
-            except (TypeError, ValueError):
-                params = {}
-            for kw in ("check_rep", "check_vma"):
-                if kw in params:
-                    kwargs[kw] = False
-                    break
         f = shard_map(
             per_device_fn,
             mesh=mesh,
             in_specs=tuple(specs) if nin > 1 else specs[0],
             out_specs=P(AXIS),
-            **kwargs,
+            check_vma=not pallas,
         )
         if donate:
             self.comm.mesh.arena.note_donation()

@@ -2,7 +2,7 @@
 
 ≈ the MCA dynamic-component loader (``mca_base_component_repository``,
 SURVEY.md §2.1 "MCA base"): native pieces are optional shared objects
-discovered/built at runtime; everything degrades gracefully to the pure
+discovered/built at runtime; the convertor degrades to the pure
 jax/numpy paths when the toolchain is absent.
 
 * ``libtpumpi.so`` — the C ``mpi.h`` ABI (native/src/shim.c).
@@ -33,21 +33,25 @@ def toolchain_available() -> bool:
     return shutil.which("gcc") is not None and shutil.which("g++") is not None
 
 
-def build(force: bool = False) -> bool:
-    """Run the native Makefile (idempotent, cached per process)."""
+def build(force: bool = False) -> None:
+    """Run the native Makefile (cached per process).  ``force`` remakes
+    every target from the sources (``make -B``): a ``native/build/``
+    copied from another tree bakes that tree's package root into
+    libtpumpi and its binaries.  Raises when there is no toolchain or
+    the build fails."""
     global _built
     with _lock:
         if _built and not force:
-            return True
+            return
         if not toolchain_available():
-            return False
+            raise RuntimeError("no C toolchain (gcc/g++) on PATH")
         r = subprocess.run(
-            ["make", "-C", str(NATIVE_DIR)], capture_output=True, text=True
+            ["make", "-C", str(NATIVE_DIR)] + (["-B"] if force else []),
+            capture_output=True, text=True,
         )
         if r.returncode != 0:
             raise RuntimeError(f"native build failed:\n{r.stdout}\n{r.stderr}")
         _built = True
-        return True
 
 
 def lib_path(name: str) -> Path:
@@ -60,9 +64,8 @@ def load_convertor() -> ctypes.CDLL | None:
     if _convertor is not None:
         return _convertor or None
     try:
-        if not lib_path("tpuconvertor").exists() and not build():
-            _convertor = False
-            return None
+        if not lib_path("tpuconvertor").exists():
+            build()
         lib = ctypes.CDLL(str(lib_path("tpuconvertor")))
         I64P = ctypes.POINTER(ctypes.c_int64)
         lib.tpuconv_pack.argtypes = [
@@ -90,8 +93,7 @@ def compile_mpi_program(
     ≈ the reference's ``mpicc`` wrapper: adds -I for mpi.h, links
     -ltpumpi with an rpath so the binary runs without LD_LIBRARY_PATH.
     """
-    if not build():
-        raise RuntimeError("no C toolchain available")
+    build()
     out = Path(output)
     cmd = [
         "gcc", "-O2", "-Wall",

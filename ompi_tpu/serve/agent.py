@@ -192,9 +192,12 @@ class LaunchAgent:
         # telemetry ingest address from the COMMAND, not the inherited
         # env: after a daemon restart the agent's environment still
         # names the dead predecessor's ingest port, and a worker born
-        # pointing there would publish into the void forever
+        # pointing there would publish into the void forever; its chip
+        # is one of this host's, counted among this host's ranks
         env = worker_env(rank, self.np, self.kvs_addr,
-                         telemetry_addr=telemetry)
+                         telemetry_addr=telemetry,
+                         host_slot=(sorted(self.ranks).index(rank),
+                                    len(self.ranks)))
         if incarnation:
             env[ENV_INCARNATION] = str(incarnation)
         p = subprocess.Popen(

@@ -562,10 +562,13 @@ class TpuDaemon:
                      if self.pidfile else {})
         if self._host_ids_env:
             extra[ENV_HOST_IDS] = self._host_ids_env
+        # this host's ranks share its chips: count from 0 among them
+        local = [r for r, h in enumerate(self._rank_hid) if h is None]
         env = worker_env(
             rank, self.np, self.server.address, mca=self._worker_mca(),
             cpu_devices=self.cpu_devices, extra_env=extra or None,
-            telemetry_addr=self.aggregator.ingest_address)
+            telemetry_addr=self.aggregator.ingest_address,
+            host_slot=(local.index(rank), len(local)))
         if self._incarnation[rank]:
             env[ENV_INCARNATION] = str(self._incarnation[rank])
         p = subprocess.Popen(

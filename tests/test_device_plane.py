@@ -237,24 +237,13 @@ def mesh(devices):
 def _spmd(mesh, fn, x, **kwargs):
     import jax
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ompi_tpu.mesh import AXIS
 
-    import inspect
-
-    kw = {}
-    params = inspect.signature(shard_map).parameters
-    for k in ("check_rep", "check_vma"):
-        if k in params:
-            kw[k] = False
-            break
     shard = shard_map(lambda v: fn(v[0])[None], mesh=mesh,
-                      in_specs=P(AXIS), out_specs=P(AXIS), **kw)
+                      in_specs=P(AXIS), out_specs=P(AXIS), check_vma=False)
     return np.asarray(jax.jit(shard)(x))
 
 

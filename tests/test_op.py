@@ -141,10 +141,7 @@ def test_noncommutative_recursive_doubling_consistent():
     """Non-commutative user op through recursive doubling must produce
     the rank-ordered fold on every rank (regression: operand order)."""
     import jax
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5: experimental namespace
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from ompi_tpu.coll import base as cb
     from ompi_tpu.mesh import AXIS

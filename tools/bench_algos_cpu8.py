@@ -24,12 +24,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5: experimental namespace (same signature)
-    from jax.experimental.shard_map import shard_map
-
-jax.config.update("jax_platforms", "cpu")
+from jax import shard_map
 
 from ompi_tpu.coll import base
 from ompi_tpu.mesh import AXIS
@@ -76,21 +71,15 @@ BARRIER = {
 
 
 def timed(fn, x, iters, out_specs=None):
-    import inspect
-
-    # jax renamed check_rep → check_vma across versions; pass whichever
-    # this jax understands (the replication check must be OFF: the
-    # schedules return rank-varying intermediates on purpose)
-    params = inspect.signature(shard_map).parameters
-    check_kw = ({"check_vma": False} if "check_vma" in params
-                else {"check_rep": False} if "check_rep" in params else {})
+    # the replication check must be OFF: the schedules return
+    # rank-varying intermediates on purpose
     f = jax.jit(
         shard_map(
             fn, mesh=MESH,
             in_specs=jax.sharding.PartitionSpec(AXIS),
             out_specs=(jax.sharding.PartitionSpec(AXIS)
                        if out_specs is None else out_specs),
-            **check_kw,
+            check_vma=False,
         )
     )
     jax.block_until_ready(f(x))  # compile
