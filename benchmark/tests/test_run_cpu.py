@@ -1,0 +1,148 @@
+"""Whole runs of every cell at tiny sizes on four virtual CPU devices,
+past the harness's look for a chip: the data-driven lookup, the window,
+the comparison with the reference, and that a broken path or the
+lower-precision control comes out not correct.  Besides the committed
+cells, ``host_large`` (added by data alone, see ``conftest.bench_root``)
+drives the host-buffer path at four ranks."""
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+from benchmark import control, harness
+
+ROOT = harness.ROOT
+CELLS = [w["name"] for w in
+         json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
+CELLS += ["host_large"]
+SEED = 2**31 + 4242
+
+
+def run_cell(root, cell, traced=False, call=None, seed=SEED):
+    c = harness.load_cell(cell, root)
+    return c, harness.run(c, seed, 0.3, traced, time.perf_counter(),
+                          call=call, chip_check=False)
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["measure", "trace"])
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_whole_run_is_correct(bench_root, cell, traced):
+    c, res = run_cell(bench_root, cell, traced)
+    assert res["correct"], res["check"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    assert list(res)[-1] == "check"
+    assert res["device"]["count"] == 4
+    if traced:
+        assert {"busy_s", "window_s"} <= set(res["device"])
+        assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
+        # no device planes on the CPU: only host-span readers report
+        assert set(res["metrics"]) <= {m["name"] for m in c.per_layer}
+    else:
+        assert set(res["metrics"]) == {m["name"] for m in c.end_to_end}
+        assert "setup_s" in res["metrics"]
+
+
+def _world_call():
+    import ompi_tpu.api as api
+    from ompi_tpu.op import SUM
+
+    world = api.init()
+    return lambda x: world.allreduce(x, SUM)
+
+
+def _no_exchange(x):
+    return x
+
+
+def _half_left_out(x):
+    out = _world_call()(x)
+    half = x.shape[1] // 2
+    if isinstance(out, np.ndarray):
+        out = out.copy()
+        out[:, half:] = x[:, half:]
+        return out
+    return out.at[:, half:].set(x[:, half:])
+
+
+def _device_array_to_a_host_caller(x):
+    import jax
+
+    out = _world_call()(x)
+    return jax.device_put(out) if isinstance(out, np.ndarray) else out
+
+
+def test_a_host_caller_is_owed_numpy(bench_root):
+    _, res = run_cell(bench_root, "host_large",
+                      call=_device_array_to_a_host_caller)
+    assert not res["correct"] and res["failed"] > 0
+
+
+def _answer_altered(x):
+    out = _world_call()(x)
+    if isinstance(out, np.ndarray):
+        out = out.copy()
+        out[-1, -1] += 1
+        return out
+    return out.at[-1, -1].add(1)
+
+
+@pytest.mark.parametrize("fault", [_no_exchange, _half_left_out,
+                                   _answer_altered, control.bf16_sum],
+                         ids=["no_exchange", "half_left_out",
+                              "answer_altered", "bf16_control"])
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_broken_path_is_not_correct(bench_root, cell, fault):
+    _, res = run_cell(bench_root, cell, call=fault)
+    assert not res["correct"], res["check"]
+    assert res["check"]["max_err_eps"]["value"] > \
+        res["check"]["max_err_eps"]["limit"]
+
+
+def test_a_new_cell_is_found_with_no_code_edit(bench_root):
+    """``host_large`` came as a config file and BENCHMARK.json entries;
+    a new mix comes as one more file."""
+    (bench_root / "benchmark" / "traffic" / "mid.json").write_text(json.dumps(
+        {"loop": "closed", "callers": 1, "sizes_bytes": [256, 8192],
+         "repeats_per_cycle": 2, "inputs_per_size": 1,
+         "checked_per_size": 1, "trace_seconds": 0.2}))
+    spec = json.loads((bench_root / "BENCHMARK.json").read_text())
+    spec["workloads"].append(
+        {"name": "host_mid", "config": "osu_allreduce_host",
+         "traffic": "mid", "chips": 4, "why": "a cell added by data alone"})
+    for m in spec["end_to_end"]:
+        if m["name"] == "busbw_GBps":
+            m["workloads"].append("host_mid")
+    (bench_root / "BENCHMARK.json").write_text(json.dumps(spec))
+    c, res = run_cell(bench_root, "host_mid")
+    assert c.mix["sizes_bytes"] == [256, 8192]
+    assert c.config["buffers"] == "host" and c.chips == 4
+    assert res["correct"] and res["check"]["sizes_compared"]["value"] == 2
+    assert set(res["metrics"]) == {"busbw_GBps", "setup_s"}
+
+
+def test_a_split_metric_falls_back_to_its_quantity_s_reader():
+    read = harness.load_reader(ROOT, "idle_share.some_later_target")
+    assert read is not None
+    with pytest.raises(FileNotFoundError):
+        harness.load_reader(ROOT, "no_such_quantity.busbw")
+
+
+def test_an_unknown_cell_is_refused(tiny_root):
+    with pytest.raises(KeyError):
+        harness.load_cell("no_such_cell", tiny_root)
+
+
+def test_the_command_refuses_a_host_without_a_tpu():
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    r = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload", "device_large",
+         "--seed", str(SEED), "--seconds", "1", "--trace", "0"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=120)
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr
+    assert not any(ln.startswith("{") for ln in r.stdout.splitlines())

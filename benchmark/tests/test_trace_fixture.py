@@ -1,0 +1,82 @@
+"""The trace reduction on profiler traces recorded by the benchmark on
+TPU v5e chips (``fixtures/``, gzipped ``.xplane.pb``): the planes it
+keys on, the copies and the all-reduce it finds, and the numbers the
+chip runs printed from the same traces."""
+
+import gzip
+from pathlib import Path
+
+import pytest
+
+from benchmark import arith, trace
+
+FIXTURES = Path(__file__).resolve().parent / "fixtures"
+
+
+def load(name: str) -> trace.Trace:
+    import jax
+
+    raw = gzip.decompress((FIXTURES / name).read_bytes())
+    return trace.from_profile(jax.profiler.ProfileData.from_serialized_xspace(raw))
+
+
+@pytest.fixture(scope="module")
+def host_small():
+    """One cycle (128 calls, 4 B-64 KiB) of numpy buffers through one
+    rank on one chip: every call stages in and out."""
+    return load("host_small_v5e_1chip.xplane.pb.gz")
+
+
+def test_host_path_copies_are_found_on_the_host_plane(host_small):
+    assert list(host_small.devices) == [0]
+    assert len(host_small.spans["bench.call"]) == 128
+    kinds = [d for _, _, d, _ in host_small.transfers]
+    assert kinds.count("h2d") == 128 and kinds.count("d2h") == 128
+    # one rank: the donated copy program runs no operation on the chip
+    assert {trace.opcode(n) for _, _, n in host_small.devices[0]} == {"h2d", "d2h"}
+
+
+def test_host_path_copies_count_as_busy(host_small):
+    per_copy = [e - s for s, e, _, _ in host_small.transfers]
+    assert all(t > 0 for t in per_copy)
+    assert sum(per_copy) / 1e9 == pytest.approx(host_small.busy_s(), rel=0.01)
+    assert host_small.window_s() == pytest.approx(0.185507154)
+    assert host_small.busy_s() == pytest.approx(0.051282095)
+    assert host_small.idle_share() * 100 == pytest.approx(72.35573189808086)
+    idle = dict(host_small.breakdown()["idle_gaps"])
+    assert idle["bench.call"] == pytest.approx(0.131995639)
+
+
+@pytest.fixture(scope="module")
+def device_large():
+    """One cycle (10 calls, 2 of each of 1-256 MiB per rank) of
+    device_large on a v5e 2x2."""
+    return load("device_large_v5e_2x2.xplane.pb.gz")
+
+
+def test_four_chip_planes_each_run_one_all_reduce_per_call(device_large):
+    assert list(device_large.devices) == [0, 1, 2, 3]
+    assert len(device_large.spans["bench.call"]) == 10
+    assert device_large.transfers == []
+    for ops in device_large.devices.values():
+        assert [trace.opcode(n) for _, _, n in ops] == ["all-reduce"] * 10
+
+
+def test_ici_roofline_arithmetic_matches_the_chip_run(device_large):
+    from types import SimpleNamespace
+
+    from benchmark.harness import ROOT, load_reader
+
+    sizes = [1 << 20, 4 << 20, 16 << 20, 64 << 20, 256 << 20]
+    run = SimpleNamespace(trace=device_large, n=4, sizes_bytes=sizes,
+                          calls=[i for i in range(5) for _ in range(2)],
+                          device_kind="TPU v5 lite")
+    roof = load_reader(ROOT, "ici_roofline")(run)
+    assert roof == pytest.approx(42.71048351084056)
+    assert 0 < roof <= 100
+    # the 256 MiB call is bound by the ICI, not by HBM
+    assert arith.allreduce_floor_s(256 << 20, 4,
+                                   arith.peaks("TPU v5 lite"))[1] == "ici"
+    assert load_reader(ROOT, "idle_share.busbw")(run) == pytest.approx(
+        47.02417412997405)
+    assert device_large.busy_s() == pytest.approx(0.01255772775)
