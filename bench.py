@@ -371,13 +371,7 @@ def run(max_bytes: int, iters: int, suite_max: int, step: int) -> dict:
             "fw_GBs": round(nb / min(t) / 1e9, 3),
         })
     arena1 = world.mesh.arena.stats()
-    # -1 = "unobservable on this backend" sentinel: pass through, never
-    # difference it into a fake measured zero
-    arena = {
-        k: (arena1[k] if isinstance(arena1[k], bool) or arena1[k] == -1
-            else arena1[k] - arena0.get(k, 0))
-        for k in arena1
-    }
+    arena = {k: arena1[k] - arena0.get(k, 0) for k in arena1}
     arena["end_state"] = arena1
 
     return {

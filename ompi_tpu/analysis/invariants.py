@@ -385,8 +385,9 @@ def _import_aliases(tree: ast.Module) -> dict[str, str]:
 
 def _latch_names(fn: ast.AST | None) -> set[str]:
     """Names assigned the t0-latch idiom in this function:
-    ``t0 = trace.now() if _trace._enabled else 0`` — a later ``if t0:``
-    then dominates the hook call with the gate, one hop removed."""
+    ``t0 = time.perf_counter_ns() if _metrics._enabled else 0`` — a
+    later ``if t0:`` then dominates the hook call with the gate, one hop
+    removed."""
     out: set[str] = set()
     if fn is None:
         return out

@@ -391,7 +391,7 @@ class Comm(PersistentP2PMixin):
     # the zero-per-call-setup hot loop of SURVEY.md §3.3 (VERDICT r1 #1).
 
     def _fast_fn(self, slot: str, base: str, key: tuple, args: tuple,
-                 donate: bool = False):
+                 donate: bool = False, sp=None):
         """Cached-or-resolved compiled callable for this call signature,
         or None when the winning module exposes no resolver (host/
         monitoring modules) — then the caller takes the table path.
@@ -401,7 +401,10 @@ class Comm(PersistentP2PMixin):
         accelerator component allows it.  The donate decision is read
         at RESOLUTION time only and baked into the cached callable
         (key carries the flag; store-version invalidation picks up
-        --mca accelerator_tpu_donate_staged changes)."""
+        --mca accelerator_tpu_donate_staged changes).
+
+        ``sp``: the call's open api span, when tracing is on — a miss
+        records its resolution as the child span ``coll.resolve``."""
         ctx = mca._default
         try:
             ent = self._fast[key]
@@ -419,7 +422,11 @@ class Comm(PersistentP2PMixin):
         ver = ctx.store.version
         if donate:
             donate = bool(ctx.store.get("accelerator_tpu_donate_staged", True))
-        fn = resolve(base, *args, donate=donate)
+        if sp is None:
+            fn = resolve(base, *args, donate=donate)
+        else:
+            with sp.child("coll", "resolve"):
+                fn = resolve(base, *args, donate=donate)
         if fn is None:
             return None
         if len(self._fast) > 4096:  # user-op churn backstop
@@ -445,19 +452,46 @@ class Comm(PersistentP2PMixin):
             return _trace.wrap_call("api", slot, fn, comm=self.name)
         return fn
 
-    def _dispatch(self, slot: str, key: tuple, args: tuple, host: bool):
+    def _api_span(self, slot: str, x):
+        """Open the api-layer span of one collective call (tracing on)."""
+        return _trace.span("api", slot, comm=self.name,
+                           seq=_trace.next_seq(self.name, slot),
+                           nbytes=spc.payload_nbytes(x))
+
+    def _dispatch(self, slot: str, key: tuple, args: tuple, host: bool,
+                  sp=None):
+        """Run one blocking collective through the compiled fast path,
+        else the coll table.  ``sp``: a traced caller's open api span."""
         self._ft_guard()
-        t0 = _trace.now() if _trace._enabled else 0
+        if _trace._enabled:
+            return self._dispatch_traced(slot, key, args, host, sp)
         # host inputs were staged into a buffer this call owns → the
         # arena's donating program variant may consume it (key carries
         # the flag so host/device callers never share a cache entry)
         fn = self._fast_fn(slot, slot, key + (host,), args, donate=host)
         out = fn(args[0]) if fn is not None else self.coll.lookup(slot)(*args)
-        if t0:
-            _trace.complete("api", slot, t0, comm=self.name,
-                            seq=_trace.next_seq(self.name, slot),
-                            nbytes=spc.payload_nbytes(args[0]))
         return self.mesh.stage_out(out) if host else out
+
+    def _dispatch_traced(self, slot: str, key: tuple, args: tuple,
+                         host: bool, sp):
+        """_dispatch with tracing on, inside the caller's api span or
+        one of its own; ``coll.launch`` covers the compiled program's
+        call."""
+        own = sp is None
+        if own:
+            sp = self._api_span(slot, args[0])
+        try:
+            fn = self._fast_fn(slot, slot, key + (host,), args, donate=host,
+                               sp=sp)
+            if fn is None:
+                out = self.coll.lookup(slot)(*args)
+            else:
+                with sp.child("coll", "launch"):
+                    out = fn(args[0])
+            return self.mesh.stage_out(out) if host else out
+        finally:
+            if own:
+                sp.end()
 
     def _dispatch_i(self, slot: str, base: str, key: tuple, args: tuple,
                     host: bool) -> Request:
@@ -465,14 +499,19 @@ class Comm(PersistentP2PMixin):
         callable as the blocking slot (shared key), wrapped in an
         ArrayRequest (async XLA dispatch ↔ libnbc schedule)."""
         self._ft_guard()
-        t0 = _trace.now() if _trace._enabled else 0
+        if _trace._enabled:
+            with self._api_span(slot, args[0]) as sp:
+                fn = self._fast_fn(slot, base, key + (host,), args,
+                                   donate=host, sp=sp)
+                if fn is None:
+                    req = self.coll.lookup(slot)(*args)
+                else:
+                    with sp.child("coll", "launch"):
+                        req = ArrayRequest(fn(args[0]))
+            return _wrap_unstage(req, self, host)
         fn = self._fast_fn(slot, base, key + (host,), args, donate=host)
         req = (ArrayRequest(fn(args[0])) if fn is not None
                else self.coll.lookup(slot)(*args))
-        if t0:
-            _trace.complete("api", slot, t0, comm=self.name,
-                            seq=_trace.next_seq(self.name, slot),
-                            nbytes=spc.payload_nbytes(args[0]))
         return _wrap_unstage(req, self, host)
 
     def _coll_call(self, slot: str, x, depth: int, op: Op | None = None,
@@ -483,8 +522,9 @@ class Comm(PersistentP2PMixin):
         call on a mesh-resident buffer) the compiled callable is
         returned without tuple hashing or arg checks — those are pure
         functions of the signature and already passed once
-        (SURVEY.md §3.3 zero-setup hot loop).  The key is built ONCE
-        here, so _dispatch and the hot store can never diverge."""
+        (SURVEY.md §3.3 zero-setup hot loop)."""
+        if _trace._enabled:
+            return self._coll_call_traced(slot, x, depth, op, root)
         if (
             self._ft is None
             and type(x) in _JAX_ARRAY_TYPES
@@ -499,14 +539,42 @@ class Comm(PersistentP2PMixin):
             ):
                 if spc._attached:
                     spc.inc(slot)
-                if _trace._enabled:
-                    t0 = _trace.now()
-                    out = c[6](x)
-                    _trace.complete("api", slot, t0, comm=self.name,
-                                    seq=_trace.next_seq(self.name, slot),
-                                    nbytes=spc.payload_nbytes(x), hot=True)
-                    return out
                 return c[6](x)
+        return self._coll_miss(slot, x, depth, op, root)
+
+    def _coll_call_traced(self, slot: str, x, depth: int, op: Op | None,
+                          root: int | None):
+        """_coll_call with tracing on: one api span covers the whole
+        call, from the entry on; its ``hot`` arg says whether the
+        last-signature cache served it (the same test as
+        _coll_call's), and ``coll.launch`` covers the compiled program's
+        call."""
+        with self._api_span(slot, x) as sp:
+            if (
+                self._ft is None
+                and type(x) in _JAX_ARRAY_TYPES
+                and x.sharding is self._ok_sharding
+            ):
+                c = self._hot.get(slot)
+                if (
+                    c is not None
+                    and c[0] is op and c[1] == root
+                    and c[2] == x.shape and c[3] == x.dtype
+                    and c[4] is mca._default and c[5] == c[4].store.version
+                ):
+                    if spc._attached:
+                        spc.inc(slot)
+                    sp.args["hot"] = 1
+                    with sp.child("coll", "launch"):
+                        return c[6](x)
+            sp.args["hot"] = 0
+            return self._coll_miss(slot, x, depth, op, root, sp)
+
+    def _coll_miss(self, slot: str, x, depth: int, op: Op | None,
+                   root: int | None, sp=None):
+        """_coll_call past the last-signature cache: check, stage and
+        dispatch, then remember the signature.  The key is built ONCE
+        here, so _dispatch and the hot store can never diverge."""
         if op is not None:
             self._check_op(op, x)
         if root is not None:
@@ -515,7 +583,7 @@ class Comm(PersistentP2PMixin):
         key = (slot, op, root, xd.shape, xd.dtype)
         args = (xd,) + ((op,) if op is not None else ()) \
             + ((root,) if root is not None else ())
-        out = self._dispatch(slot, key, args, host)
+        out = self._dispatch(slot, key, args, host, sp)
         if not host:
             ent = self._fast.get(key + (False,))
             if ent is not None:

@@ -812,28 +812,33 @@ class MultiProcComm(PersistentP2PMixin):
                     for p in range(self.nprocs)))
         if not world_shaped:
             return self._replace_partial(name, timeout)
-        t0 = _trace.now() if _trace._enabled else 0
+        sp = _trace.span("ft", "replace", comm=self.name) \
+            if _trace._enabled else None
         import time as _time
 
         tw0 = _time.monotonic()
-        if not ctx.rejoined:
-            cid = self._replace_rejoin(timeout)
-        else:
-            live = self._live_procs()
-            dead = sorted(set(range(self.nprocs)) - set(live))
-            if not dead:
-                # without a restoration round there is no agreement
-                # exchange, and per-process CID reservation would
-                # diverge — nothing to replace is an error, like
-                # MPIX semantics for recovery calls outside recovery
-                raise MPICommError(
-                    "replace: no failed ranks on this communicator")
-            proposals = self._replace_recover(sorted(live), dead, timeout)
-            cid = _reserve_cid_block(max(int(c) for c in proposals), 1)
-        sub = self._replace_build(cid, name)
-        if _trace._enabled:
-            _trace.complete("ft", "replace", t0, comm=self.name,
-                            cid=int(cid))
+        try:
+            if not ctx.rejoined:
+                cid = self._replace_rejoin(timeout)
+            else:
+                live = self._live_procs()
+                dead = sorted(set(range(self.nprocs)) - set(live))
+                if not dead:
+                    # without a restoration round there is no agreement
+                    # exchange, and per-process CID reservation would
+                    # diverge — nothing to replace is an error, like
+                    # MPIX semantics for recovery calls outside recovery
+                    raise MPICommError(
+                        "replace: no failed ranks on this communicator")
+                proposals = self._replace_recover(sorted(live), dead,
+                                                  timeout)
+                cid = _reserve_cid_block(max(int(c) for c in proposals), 1)
+            sub = self._replace_build(cid, name)
+            if sp is not None:
+                sp.args["cid"] = int(cid)
+        finally:
+            if sp is not None:
+                sp.end()
         # recovery observability: the restoration's end-to-end heal
         # latency, flight-recorded (→ telemetry event) on every
         # participant — no-op unless metrics are enabled
@@ -885,33 +890,37 @@ class MultiProcComm(PersistentP2PMixin):
         import time as _time
 
         tw0 = _time.monotonic()
-        t0 = _trace.now() if _trace._enabled else 0
-        live = self._live_procs()
-        dead = sorted(set(range(self.nprocs)) - set(live))
-        if not dead:
-            raise MPICommError(
-                "replace: no failed ranks on this communicator")
-        recipe = self._partial_recipe(name)
-        live_roots = [self.dcn.root_proc_of(p) for p in live]
-        dead_roots = [self.dcn.root_proc_of(p) for p in dead]
-        proposals = self._partial_rounds(live_roots, dead_roots,
-                                         timeout, recipe)
-        cid = _reserve_cid_block(max(int(c) for c in proposals), 1)
-        sub = self._make_sub(
-            "replaced", cid, list(range(self.size)),
-            [p for p in range(self.nprocs)
-             for _ in range(self.proc_sizes[p])],
-            list(range(self.nprocs)))
-        sub.name = recipe["name"]
-        # metadata in WORLD coordinates, matching the reborn side's
-        # recipe-built comm: _make_sub relative to the OLD sub yields
-        # a [0..size) group, and a SECOND partial repair would publish
-        # those sub-local ranks as a "world-coordinate" recipe — wrong
-        # membership whenever the sub's ranks aren't [0..size)
-        sub.group = Group(list(self.group.ranks))
-        if _trace._enabled:
-            _trace.complete("ft", "replace", t0, comm=self.name,
-                            cid=int(cid))
+        sp = _trace.span("ft", "replace", comm=self.name) \
+            if _trace._enabled else None
+        try:
+            live = self._live_procs()
+            dead = sorted(set(range(self.nprocs)) - set(live))
+            if not dead:
+                raise MPICommError(
+                    "replace: no failed ranks on this communicator")
+            recipe = self._partial_recipe(name)
+            live_roots = [self.dcn.root_proc_of(p) for p in live]
+            dead_roots = [self.dcn.root_proc_of(p) for p in dead]
+            proposals = self._partial_rounds(live_roots, dead_roots,
+                                             timeout, recipe)
+            cid = _reserve_cid_block(max(int(c) for c in proposals), 1)
+            sub = self._make_sub(
+                "replaced", cid, list(range(self.size)),
+                [p for p in range(self.nprocs)
+                 for _ in range(self.proc_sizes[p])],
+                list(range(self.nprocs)))
+            sub.name = recipe["name"]
+            # metadata in WORLD coordinates, matching the reborn side's
+            # recipe-built comm: _make_sub relative to the OLD sub yields
+            # a [0..size) group, and a SECOND partial repair would publish
+            # those sub-local ranks as a "world-coordinate" recipe — wrong
+            # membership whenever the sub's ranks aren't [0..size)
+            sub.group = Group(list(self.group.ranks))
+            if sp is not None:
+                sp.args["cid"] = int(cid)
+        finally:
+            if sp is not None:
+                sp.end()
         from ompi_tpu.metrics import flight as _flight
 
         _flight.record(

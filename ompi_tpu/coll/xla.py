@@ -42,6 +42,7 @@ from ompi_tpu.core.errors import MPIOpError
 from ompi_tpu.mesh import AXIS
 from ompi_tpu.op.op import Op
 from ompi_tpu.request import ArrayRequest, PersistentRequest, Request
+from ompi_tpu.trace import core as _trace
 from . import base as algos
 from .module import CollModule
 
@@ -100,7 +101,13 @@ class XlaCollModule(CollModule):
         if fn is None:
             if len(self._cache) > 4096:  # user-op churn backstop (ops key
                 self._cache.clear()      # by identity; see Comm._fast)
-            fn = builder()
+            if _trace._enabled:
+                # a miss of the program cache is the presence of this
+                # span (inside coll.resolve on the api fast path)
+                with _trace.span("coll", "build"):
+                    fn = builder()
+            else:
+                fn = builder()
             self._cache[key] = fn
         return fn
 

@@ -635,22 +635,23 @@ class TcpTransport:
         try:
             if pr.sock is None:
                 reconnect = pr.epoch > 0
-                t0 = _trace.now() if _trace._enabled else 0
+                sp = _trace.span("dcn", "reconnect", peer=address) \
+                    if reconnect and _trace._enabled else None
                 tw0 = time.monotonic()
-                pr.sock, ack = self._dial_backoff(address, retry=retry)
-                if ack is not None:
-                    # a control dial (retry=False) skips the handshake;
-                    # the prior epoch's ack stays — acks are monotone
-                    # per receiver, so a stale value is a safe lower
-                    # bound for the resend-skip decision
-                    pr.last_ack = ack
-                pr.epoch += 1
+                try:
+                    pr.sock, ack = self._dial_backoff(address, retry=retry)
+                    if ack is not None:
+                        # a control dial (retry=False) skips the
+                        # handshake; the prior epoch's ack stays — acks
+                        # are monotone per receiver, so a stale value is
+                        # a safe lower bound for the resend-skip decision
+                        pr.last_ack = ack
+                    pr.epoch += 1
+                finally:
+                    if sp is not None:
+                        sp.end(epoch=pr.epoch, ack=pr.last_ack)
                 if reconnect:
                     self.stats["reconnects"] += 1
-                    if _trace._enabled:
-                        _trace.complete("dcn", "reconnect", t0,
-                                        peer=address, epoch=pr.epoch,
-                                        ack=pr.last_ack)
                     # recovery observability: every redial leaves a
                     # flight record (and thus a telemetry event) with
                     # the new epoch, the confirmed seq watermark, and
@@ -792,15 +793,12 @@ class TcpTransport:
 
     def send(self, address: str, envelope: dict, payload: np.ndarray) -> None:
         if _trace._enabled:
-            t0 = _trace.now()
-            try:
+            nb = int(getattr(payload, "nbytes", 0) or 0)
+            with _trace.span("dcn", "send", nbytes=nb, peer=address,
+                             proto=self._proto_of(nb),
+                             **({"cid": envelope["cid"]}
+                                if "cid" in envelope else {})):
                 self._send(address, envelope, payload)
-            finally:
-                nb = int(getattr(payload, "nbytes", 0) or 0)
-                _trace.complete("dcn", "send", t0, nbytes=nb, peer=address,
-                                proto=self._proto_of(nb),
-                                **({"cid": envelope["cid"]}
-                                   if "cid" in envelope else {}))
             return
         self._send(address, envelope, payload)
 

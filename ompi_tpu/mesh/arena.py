@@ -8,17 +8,13 @@ enter through the host (numpy) API.
 **Why there is no literal "H2D into a pooled buffer" path.**  Under
 PJRT/IFRT a host→device transfer *always* materializes a new logical
 buffer — there is no public API to overwrite an existing device
-allocation with host bytes (and some backends do not implement
-``unsafe_buffer_pointer`` at all).  The mpool free-list
-therefore lives at three levels, all of which this class owns or
-accounts:
+allocation with host bytes).  The mpool free-list therefore lives at
+three levels, all of which this class owns or accounts:
 
 * **runtime allocator recycling** — successive ``stage_in`` calls of
   the same signature land on XLA's BFC free list, so steady-state
-  staging reuses the same HBM *addresses*.  Where the backend exposes
-  buffer pointers this is measured per signature
-  (``addr_reuse``/``addr_new``); on backends without pointer access
-  the counters report -1 (unobservable, not zero).
+  staging reuses the same HBM *addresses* (the allocator's doing; not
+  counted here).
 * **buffer donation** — compiled collectives for shape-preserving ops
   are built with ``donate_argnums`` when their input is the
   framework-owned staged buffer, so XLA writes the result into the
@@ -51,22 +47,17 @@ from ompi_tpu.tool import spc
 #: (tokens/scratch); deeper lists would just pin HBM
 _POOL_CAP = 4
 
-#: per-signature cap on remembered addresses (bounds _addrs growth)
-_ADDR_CAP = 64
-
 
 class HbmArena:
     """Per-mesh staging manager: free-lists device temporaries, counts
-    H2D traffic, allocator-level address reuse, and donation
-    resolutions.  Cheap by construction — the per-call cost is one
-    attribute test plus integer adds; everything signature-level
-    (donation) is accounted at resolution time, not per call."""
+    H2D traffic and donation resolutions.  Cheap by construction — the
+    per-call cost is one attribute test plus integer adds; everything
+    signature-level (donation) is accounted at resolution time, not per
+    call."""
 
     __slots__ = (
         "stage_calls", "stage_bytes", "donate_signatures",
-        "pool_hits", "pool_allocs", "addr_reuse", "addr_new",
-        "_lock", "_free", "_addrs", "_ptr_ok", "_addr_overflow",
-        "_addr_sample",
+        "pool_hits", "pool_allocs", "_lock", "_free",
     )
 
     def __init__(self):
@@ -76,49 +67,11 @@ class HbmArena:
         self.donate_signatures = 0
         self.pool_hits = 0
         self.pool_allocs = 0
-        self.addr_reuse = 0
-        self.addr_new = 0
         self._lock = threading.Lock()
         #: (shape, dtype str) → free device buffers
         self._free: dict[tuple, list] = {}
-        #: (shape, dtype str) → HBM addresses previously handed out
-        self._addrs: dict[tuple, set] = {}
-        #: backend exposes unsafe_buffer_pointer
-        self._ptr_ok = True
-        #: a signature overflowed _ADDR_CAP — reuse counts undercount
-        self._addr_overflow = False
-        #: stage_in calls seen by the address sampler
-        self._addr_sample = 0
 
     # -- staging accounting --------------------------------------------
-
-    def _note_addr(self, d: jax.Array, key: tuple) -> None:
-        """Track whether the runtime allocator recycled an address we
-        have staged to before (the BFC free list acting as the mpool).
-        Pointer extraction costs tens of us, so stage_in SAMPLES it
-        (first 8 calls, then 1-in-8) — the counters are a recycling
-        indicator, not an exact census."""
-        try:
-            shards = d.addressable_shards
-            p = shards[0].data.unsafe_buffer_pointer() if shards \
-                else d.unsafe_buffer_pointer()
-        except Exception:
-            self._ptr_ok = False
-            return
-        with self._lock:
-            if len(self._addrs) > 512:  # unbounded-signature backstop
-                self._addrs.clear()
-            seen = self._addrs.setdefault(key, set())
-            if p in seen:
-                self.addr_reuse += 1
-            else:
-                if len(seen) < _ADDR_CAP:
-                    seen.add(p)
-                else:
-                    # can no longer distinguish recycled from fresh for
-                    # this signature — flag it instead of lying
-                    self._addr_overflow = True
-                self.addr_new += 1
 
     def stage_in(self, host_array: np.ndarray, sharding) -> jax.Array:
         with self._lock:
@@ -127,12 +80,7 @@ class HbmArena:
         if spc.attached():
             spc.inc("arena_stage_in")
             spc.inc("arena_stage_bytes", host_array.nbytes)
-        d = jax.device_put(host_array, sharding)
-        if self._ptr_ok:
-            self._addr_sample += 1
-            if self._addr_sample <= 8 or (self._addr_sample & 7) == 0:
-                self._note_addr(d, (host_array.shape, host_array.dtype.str))
-        return d
+        return jax.device_put(host_array, sharding)
 
     def note_donation(self) -> None:
         """A collective signature resolved to a donating program."""
@@ -187,7 +135,4 @@ class HbmArena:
                 "donate_signatures": self.donate_signatures,
                 "pool_hits": self.pool_hits,
                 "pool_allocs": self.pool_allocs,
-                "addr_reuse": self.addr_reuse if self._ptr_ok else -1,
-                "addr_new": self.addr_new if self._ptr_ok else -1,
-                "addr_overflow": self._addr_overflow,
             }

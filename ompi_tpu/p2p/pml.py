@@ -214,27 +214,29 @@ class MatchingEngine:
             spc.inc("send_bytes", spc.payload_nbytes(payload))
         if _account and _metrics._enabled:
             _metrics.observe_size("p2p_send", spc.payload_nbytes(payload))
-        t0 = _trace.now() if _trace._enabled else 0
-        data = _copy_payload(payload, dest_device)
-        with self._lock:
-            seq = self._next_seq()
-            posted = self._posted[dest]
-            for i, p in enumerate(posted):
-                if (p.source in (ANY_SOURCE, source)) and (p.tag in (ANY_TAG, tag)):
-                    posted.pop(i)
-                    p.request._deliver(
-                        data,
-                        Status(source, tag, _count_of(data), _nbytes_of(data)),
-                    )
-                    if t0:
-                        _trace.complete("p2p", "send", t0, src=source,
-                                        dst=dest, tag=tag, matched=True,
-                                        nbytes=_nbytes_of(data))
-                    return
-            self._unexpected[dest].append(_Unexpected(source, tag, data, seq))
-        if t0:
-            _trace.complete("p2p", "send", t0, src=source, dst=dest, tag=tag,
-                            matched=False, nbytes=_nbytes_of(data))
+        sp = _trace.span("p2p", "send", src=source, dst=dest, tag=tag) \
+            if _trace._enabled else None
+        try:
+            data = _copy_payload(payload, dest_device)
+            with self._lock:
+                seq = self._next_seq()
+                posted = self._posted[dest]
+                for i, p in enumerate(posted):
+                    if (p.source in (ANY_SOURCE, source)) and (p.tag in (ANY_TAG, tag)):
+                        posted.pop(i)
+                        p.request._deliver(
+                            data,
+                            Status(source, tag, _count_of(data), _nbytes_of(data)),
+                        )
+                        if sp is not None:
+                            sp.args.update(matched=True, nbytes=_nbytes_of(data))
+                        return
+                self._unexpected[dest].append(_Unexpected(source, tag, data, seq))
+            if sp is not None:
+                sp.args.update(matched=False, nbytes=_nbytes_of(data))
+        finally:
+            if sp is not None:
+                sp.end()
 
     # -- recv ----------------------------------------------------------
 

@@ -61,23 +61,24 @@ class Request:
     def wait(self) -> Any:
         """MPI_Wait: block until complete, return the operation result."""
         if not self._complete:
-            t0 = (time.perf_counter_ns()
-                  if (_trace._enabled or _metrics._enabled) else 0)
-            self._block()
-            self._result = self._finalize()
-            self._complete = True
+            t0 = time.perf_counter_ns() if _metrics._enabled else 0
+            # the blocked-completion span: where caller time goes while
+            # the fabric/DCN works (stragglers)
+            sp = _trace.span("request", f"{type(self).__name__}.wait") \
+                if _trace._enabled else None
+            try:
+                self._block()
+                self._result = self._finalize()
+                self._complete = True
+            finally:
+                if sp is not None:
+                    sp.end()
             if t0:
-                if _trace._enabled:
-                    # the blocked-completion span: where caller time
-                    # goes while the fabric/DCN works (stragglers)
-                    _trace.complete("request",
-                                    f"{type(self).__name__}.wait", t0)
-                if _metrics._enabled:
-                    # same blocked time as a latency histogram — the
-                    # quantitative view (p50/p99 without a trace run)
-                    _metrics.observe(
-                        f"request_wait_{type(self).__name__}", 0,
-                        time.perf_counter_ns() - t0)
+                # same blocked time as a latency histogram — the
+                # quantitative view (p50/p99 without a trace run)
+                _metrics.observe(
+                    f"request_wait_{type(self).__name__}", 0,
+                    time.perf_counter_ns() - t0)
         return self._result
 
     def _block(self) -> None:

@@ -11,18 +11,20 @@ Layout:
 
 * :mod:`ompi_tpu.trace.core` — the tracer itself: a lock-light ring
   buffer of events, gated by ``--mca trace_enable 1`` (default off:
-  one boolean check in-path, the SPC pattern);
+  one boolean check in-path, the SPC pattern); while on, each span
+  also lands on any running ``jax.profiler`` trace as
+  ``ompi.<layer>.<name>``, on the device trace's clock;
 * :mod:`ompi_tpu.trace.chrome` — Chrome trace-event JSON export
   (``chrome://tracing`` / Perfetto loadable);
 * :mod:`ompi_tpu.trace.merge` — cross-rank merge of per-process trace
   files into one timeline, collective spans keyed by (comm, op, seq).
 
-Everything here is stdlib-only so ``tools/trace_report.py`` can load
-and merge traces without importing jax.
+Everything here imports without jax (the profiler binding loads on the
+first ``enable(True)``), so ``tools/trace_report.py`` can load and
+merge traces where jax is absent.
 """
 
 from .core import (  # noqa: F401
-    complete,
     dropped,
     enable,
     enabled,
@@ -31,9 +33,9 @@ from .core import (  # noqa: F401
     instant,
     latency_histogram,
     next_seq,
-    now,
     register_vars,
     reset,
+    span,
     span_stats,
     sync_from_store,
     wrap_call,

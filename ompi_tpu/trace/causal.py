@@ -131,12 +131,17 @@ class _OpCtx:
     """Thread-local state of the collective currently in flight."""
 
     __slots__ = ("comm", "op", "seq", "arrive", "hop", "sends", "recvs",
-                 "base")
+                 "base", "span")
 
     def __init__(self, comm: str, op: str, seq: int, base: dict):
         self.comm = comm
         self.op = op
         self.seq = seq
+        #: the offline leg: one ``cx_op`` span from entry to exit,
+        #: carrying the record's scalar half (sends/recvs are emitted
+        #: live as cx instants)
+        self.span = _trace.span("causal", "cx_op", comm=comm, seq=seq,
+                                op=op) if _trace._enabled else None
         self.arrive = time.time_ns()
         self.hop = 0
         self.sends: list[list] = []
@@ -250,15 +255,10 @@ def end_op(alg: str = "") -> None:
         _records.append(row)
         _retained.append(row)
         _counters["records"] += 1
-    if _trace._enabled:
-        # the offline leg: one complete event carrying the record's
-        # scalar half (sends/recvs were emitted live as cx instants)
-        _trace.complete(
-            "causal", "cx_op",
-            _trace.now() - max(0, exit_ns - ctx.arrive),
-            comm=ctx.comm, op=ctx.op, seq=ctx.seq, alg=alg,
-            ring_us=stalls["ring"] // 1000, cts_us=stalls["cts"] // 1000,
-            dma_us=stalls["dma"] // 1000)
+    if ctx.span is not None:
+        ctx.span.end(alg=alg, ring_us=stalls["ring"] // 1000,
+                     cts_us=stalls["cts"] // 1000,
+                     dma_us=stalls["dma"] // 1000)
 
 
 def current_key() -> str | None:

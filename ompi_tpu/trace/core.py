@@ -12,11 +12,20 @@ spans, and the pvar counters must match the ring's census exactly.
 
 Event model (≈ the Chrome trace-event phases this maps onto):
 
-* **complete** (``ph="X"``): a span with a start timestamp and a
-  duration — one record per span, emitted at the END (no begin/end
-  pairing on the hot path);
+* **span** (``ph="X"``): opened by :func:`span` and closed by
+  :meth:`Span.end` or the end of its ``with`` block — one ring record
+  per span, written when it closes;
 * **instant** (``ph="i"``): a point event (an algorithm decision, a
   protocol choice).
+
+Two sinks.  Every span goes to the ring; while a ``jax.profiler``
+session records, the same span also goes to the profiler's timeline as
+a TraceMe named ``ompi.<layer>.<name>``, opened and closed at the same
+points as the ring record, with its comm, seq and args as the event's
+metadata.  There it shares the clock of the device planes, so one
+profile shows the library's host time beside the chip's work.  The
+binding (``jax.profiler.TraceAnnotation``) is imported on the first
+``enable(True)``, so this module imports without jax.
 
 Collective spans carry a ``(comm, op, seq)`` key: ``seq`` is a
 per-(comm, op) issue counter.  MPI's same-issue-order rule makes the
@@ -54,16 +63,14 @@ _seqs: dict[tuple[str, str], int] = {}
 _stats: dict[tuple[str, str], dict] = {}
 #: wall-clock anchor: (time_ns, perf_counter_ns) captured at enable
 _epoch: tuple[int, int] = (0, 0)
+#: the profiler sink, ``jax.profiler.TraceAnnotation`` (a jaxlib TraceMe);
+#: bound on the first enable(True), stays None where jax is absent
+_TraceMe = None
 
 #: histogram buckets: log2 of the span duration in µs; bucket i holds
 #: spans with 2**(i-1) µs <= dur < 2**i µs (bucket 0: sub-µs), the
 #: last bucket is open-ended.
 HIST_BUCKETS = 16
-
-
-def now() -> int:
-    """Monotonic timestamp (ns) — pair with :func:`complete`."""
-    return time.perf_counter_ns()
 
 
 def enabled() -> bool:
@@ -73,11 +80,16 @@ def enabled() -> bool:
 def enable(flag: bool = True, buffer_events: int | None = None) -> None:
     """Turn tracing on/off (tests and the MPI_T surface; production
     jobs go through ``--mca trace_enable 1`` → :func:`sync_from_store`)."""
-    global _enabled, _events, _epoch
+    global _enabled, _events, _epoch, _TraceMe
     if buffer_events is not None and buffer_events != _events.maxlen:
         _events = collections.deque(_events, maxlen=max(1, int(buffer_events)))
     if flag and not _enabled:
         _epoch = (time.time_ns(), time.perf_counter_ns())
+        if _TraceMe is None:
+            try:
+                from jax.profiler import TraceAnnotation as _TraceMe
+            except ImportError:
+                pass
     _enabled = flag
 
 
@@ -122,13 +134,9 @@ def _append(ev: tuple) -> None:
     _events.append(ev)
 
 
-def complete(layer: str, name: str, t0_ns: int, comm: str = "",
-             seq: int = -1, **args) -> None:
-    """Record a finished span: ``t0_ns`` from :func:`now` at entry."""
-    if not _enabled:
-        return
-    dur = time.perf_counter_ns() - t0_ns
-    _append(("X", t0_ns, dur, layer, name, comm, seq, args or None))
+def _record(layer: str, name: str, t0_ns: int, dur: int, comm: str,
+            seq: int, args: dict | None) -> None:
+    _append(("X", t0_ns, dur, layer, name, comm, seq, args))
     # the aggregate update is a read-modify-write reached from multiple
     # threads (transport recv threads record p2p/dcn spans concurrently
     # with the main thread's api spans), so it takes the lock — only on
@@ -148,6 +156,69 @@ def complete(layer: str, name: str, t0_ns: int, comm: str = "",
         st["hist"][min((dur // 1000).bit_length(), HIST_BUCKETS - 1)] += 1
 
 
+class Span:
+    """One open span; see :func:`span`.  ``args`` may grow until it
+    closes (``sp.args[...]``, :meth:`end` keyword arguments)."""
+
+    __slots__ = ("layer", "name", "comm", "seq", "args", "t0", "_tm")
+
+    def __init__(self, layer: str, name: str, comm: str, seq: int,
+                 args: dict):
+        self.layer, self.name, self.comm, self.seq = layer, name, comm, seq
+        self.args = args
+        tm = _TraceMe
+        if tm is not None and tm.is_enabled():  # a profiler session records
+            tm = tm(f"ompi.{layer}.{name}")
+            tm.__enter__()
+        else:
+            tm = None
+        self._tm = tm
+        self.t0 = time.perf_counter_ns()
+
+    def end(self, **args) -> None:
+        """Close the span: the profiler event, and one ring record unless
+        tracing was turned off while it was open."""
+        if args:
+            self.args.update(args)
+        tm = self._tm
+        if tm is not None:
+            meta = dict(self.args)
+            if self.comm:
+                meta["comm"] = self.comm
+            if self.seq >= 0:
+                meta["seq"] = self.seq
+            tm.set_metadata(**meta)
+        dur = time.perf_counter_ns() - self.t0
+        if tm is not None:
+            tm.__exit__(None, None, None)
+        if _enabled:
+            _record(self.layer, self.name, self.t0, dur, self.comm,
+                    self.seq, self.args or None)
+
+    def child(self, layer: str, name: str, **args) -> "Span":
+        """Open a span of a layer below, inside this one: it shares this
+        span's ``seq`` and takes no ``comm``, so only the outer span
+        carries the call's merge key."""
+        return Span(layer, name, "", self.seq, args)
+
+    def __enter__(self) -> "Span":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+def span(layer: str, name: str, comm: str = "", seq: int = -1,
+         **args) -> Span:
+    """Open a span now: ``with span(...) as sp:``, or ``sp = span(...)``
+    then ``sp.end(**more_args)`` in a ``finally``.  Call sites test the module's on-flag
+    first (``sp = _trace.span(...) if <flag> else None``), so tracing off
+    costs one boolean test per hook and builds nothing.  ``comm`` and
+    ``seq`` (from :func:`next_seq`) give a collective's api span its
+    cross-rank merge key; :meth:`Span.child` opens the spans below it."""
+    return Span(layer, name, comm, seq, args)
+
+
 def instant(layer: str, name: str, comm: str = "", **args) -> None:
     """Record a point event (decision, protocol choice, milestone)."""
     if not _enabled:
@@ -157,18 +228,15 @@ def instant(layer: str, name: str, comm: str = "", **args) -> None:
 
 
 def wrap_call(layer: str, name: str, fn, comm: str = "", **args):
-    """Closure recording one complete span around each ``fn(*a, **k)``
-    call — used where a dispatch layer hands out a callable (coll-table
+    """Closure recording one span around each ``fn(*a, **k)`` call —
+    used where a dispatch layer hands out a callable (coll-table
     lookups).  Collective api-layer wraps get a fresh seq per call."""
     keyed = layer == "api"
 
     def traced(*a, **k):
-        t0 = time.perf_counter_ns()
-        try:
+        with span(layer, name, comm, next_seq(comm, name) if keyed else -1,
+                  **args):
             return fn(*a, **k)
-        finally:
-            complete(layer, name, t0, comm=comm,
-                     seq=next_seq(comm, name) if keyed else -1, **args)
 
     traced.__name__ = f"traced_{name}"
     traced.__wrapped__ = fn

@@ -48,7 +48,9 @@ def test_disabled_by_default_records_nothing(world):
     leave the buffer empty."""
     assert not trace.enabled()
     trace.instant("api", "nope")
-    trace.complete("api", "nope", trace.now())
+    trace.span("api", "nope").end()
+    with trace.span("api", "nope"):
+        pass
     x = np.ones((N, 4), np.float32)
     world.allreduce(x, SUM)
     world.barrier()
@@ -109,18 +111,134 @@ def test_seq_counters_per_comm_op():
     assert trace.next_seq("c1", "allreduce") == 0
 
 
+# -- the profiler sink --------------------------------------------------
+
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under a jax.profiler session; the library's events on
+    the host plane as (name, start_ns, end_ns, stats), in start order."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    return sorted(
+        (e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+        for plane in pd.planes if plane.name == "/host:CPU"
+        for line in plane.lines for e in line.events
+        if e.name.startswith("ompi."))
+
+
+def test_allreduce_spans_reach_the_profiler_cold_then_hot(world, tmp_path):
+    """Tracing on, under a profiler session: a cold and a hot
+    ``Comm.allreduce`` each give one ``ompi.api.allreduce`` with
+    ``ompi.coll.launch`` inside it; only the cold call resolves, and it
+    builds its program (``ompi.coll.build`` inside the resolve: a shape
+    no earlier test used).  The ring holds the same spans, in its own
+    format, and the Chrome export keys only the api spans."""
+    import jax
+
+    x = jax.device_put(np.ones((N, 37), np.float32),
+                       world.mesh.rank_sharding())
+    trace.enable(True)
+    evs = _profile(tmp_path, lambda: [jax.block_until_ready(
+        world.allreduce(x, SUM)) for _ in range(2)])
+
+    api_evs = [e for e in evs if e[0] == "ompi.api.allreduce"]
+    assert [e[3]["seq"] for e in api_evs] == [0, 1]
+    assert [e[3]["hot"] for e in api_evs] == [0, 1]
+    assert {e[3]["nbytes"] for e in api_evs} == {N * 37 * 4}
+    assert {e[3]["comm"] for e in api_evs} == {world.name}
+    for name, s, e, st in api_evs:
+        inside = [c for c in evs if c[0].startswith("ompi.coll.")
+                  and c[3].get("seq") == st["seq"]]
+        assert all(s <= cs and ce <= e for _, cs, ce, _ in inside), inside
+        kids = [c[0] for c in inside]
+        if st["hot"]:
+            assert kids == ["ompi.coll.launch"]
+        else:
+            assert sorted(kids) == ["ompi.coll.launch", "ompi.coll.resolve"]
+            (res,) = [c for c in inside if c[0] == "ompi.coll.resolve"]
+            assert "comm" not in res[3]
+            (build,) = [c for c in evs if c[0] == "ompi.coll.build"]
+            assert res[1] <= build[1] and build[2] <= res[2]
+            assert build[3] == {}
+
+    ring = [(e[3], e[4], e[6], e[7]) for e in trace.events() if e[0] == "X"]
+    assert sorted(f"ompi.{la}.{n}" for la, n, _, _ in ring) == sorted(
+        e[0] for e in evs)
+    for layer, name, seq, args in ring:
+        assert seq == -1 if name == "build" else seq in (0, 1)
+        if layer == "api":
+            assert args["nbytes"] == N * 37 * 4 and args["hot"] in (0, 1)
+    doc = chrome.to_chrome(trace.events(), trace.epoch())
+    assert merge.collective_keys(doc) == [
+        (world.name, "allreduce", 0), (world.name, "allreduce", 1)]
+    assert trace.span_stats()[("coll", "launch")]["count"] == 2
+    assert trace.span_stats()[("coll", "build")]["count"] == 1
+
+
+def test_tracing_off_puts_nothing_on_the_profiler(world, tmp_path):
+    import jax
+
+    x = jax.device_put(np.ones((N, 5), np.float32),
+                       world.mesh.rank_sharding())
+    evs = _profile(tmp_path, lambda: [jax.block_until_ready(
+        world.allreduce(x, SUM)) for _ in range(2)])
+    assert evs == []
+    assert trace.event_count() == 0
+
+
+def test_span_api_without_a_profiler_session():
+    """The ring alone when no profiler records: one record per span,
+    args given at open, while open and at close; a child shares the
+    parent's seq and takes no comm; a span whose body raised is still
+    closed and recorded; one that outlives tracing records nothing."""
+    trace.enable(True)
+    with trace.span("api", "bcast", comm="c", seq=4, nbytes=8) as sp:
+        with sp.child("coll", "resolve", cache="hit"):
+            pass
+        sp.args["hot"] = 0
+    sp2 = trace.span("p2p", "send", dst=1)
+    sp2.args["tag"] = 3
+    sp2.end(matched=False)
+    with pytest.raises(RuntimeError):
+        with trace.span("request", "wait"):
+            raise RuntimeError("peer failed")
+    late = trace.span("dcn", "send")
+    trace.enable(False)
+    late.end()
+    evs = trace.events()
+    assert [(e[3], e[4], e[5], e[6], e[7]) for e in evs] == [
+        ("coll", "resolve", "", 4, {"cache": "hit"}),
+        ("api", "bcast", "c", 4, {"nbytes": 8, "hot": 0}),
+        ("p2p", "send", "", -1, {"dst": 1, "tag": 3, "matched": False}),
+        ("request", "wait", "", -1, None),
+    ]
+    assert evs[0][1] >= evs[1][1] and evs[0][2] <= evs[1][2]
+    assert set(trace.span_stats()) == {("coll", "resolve"), ("api", "bcast"),
+                                       ("p2p", "send"), ("request", "wait")}
+
+
 # -- chrome export + merge ---------------------------------------------
 
 
 def _record_rank(ops=3):
     for _ in range(ops):
-        t0 = trace.now()
-        trace.complete("coll", "allreduce", trace.now(), provider="han")
-        trace.complete("dcn", "send", trace.now(), nbytes=64, peer="x",
-                       proto="eager")
-        trace.complete("api", "allreduce", t0, comm="MPI_COMM_WORLD",
-                       seq=trace.next_seq("MPI_COMM_WORLD", "allreduce"),
-                       nbytes=64)
+        with trace.span("api", "allreduce", comm="MPI_COMM_WORLD",
+                        seq=trace.next_seq("MPI_COMM_WORLD", "allreduce"),
+                        nbytes=64):
+            with trace.span("coll", "allreduce", provider="han"):
+                pass
+            with trace.span("dcn", "send", nbytes=64, peer="x",
+                            proto="eager"):
+                pass
 
 
 def test_chrome_export_valid(tmp_path):

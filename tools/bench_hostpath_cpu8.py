@@ -52,11 +52,7 @@ def main() -> None:
             "fw_GBs": round(nb / med / 1e9, 3),
         })
     arena1 = world.mesh.arena.stats()
-    arena = {
-        k: (arena1[k] if isinstance(arena1[k], bool) or arena1[k] == -1
-            else arena1[k] - arena0.get(k, 0))
-        for k in arena1
-    }
+    arena = {k: arena1[k] - arena0.get(k, 0) for k in arena1}
 
     # -- non-blocking overlap at n=8, where a collective costs real
     # time (the n_ranks=1 TPU row can't show overlap: a single-chip

@@ -329,10 +329,9 @@ def selftest() -> int:
             core.register_provider(eng, eng.stats)
             flight.configure(output="", proc=rank)
             for i in range(4):
-                t0 = trace.now()
-                core.observe("dcn_p2p_send", 4096 << i, 50_000 * (i + 1))
-                trace.complete("dcn", "send", t0, nbytes=4096 << i,
-                               proto="eager", peer="peer")
+                with trace.span("dcn", "send", nbytes=4096 << i,
+                                proto="eager", peer="peer"):
+                    core.observe("dcn_p2p_send", 4096 << i, 50_000 * (i + 1))
             eng.c["stall_ns"] += 5_000_000
             eng.c["ring_stall_ns"] += 5_000_000
             rec = flight.record("recv_timeout", cid="c1", seq=7)

@@ -324,16 +324,16 @@ def selftest() -> int:
             core.reset()
             core.enable(True, buffer_events=1024)
             for i in range(3):
-                t0 = core.now()
-                core.instant("coll", "tuned_decision", coll="allreduce",
-                             algorithm="psum")
-                t1 = core.now()
-                core.complete("dcn", "send", t1, nbytes=4096, peer="peer",
-                              proto="eager")
-                core.complete("coll", "allreduce", t1, provider="han")
-                core.complete("api", "allreduce", t0, comm="MPI_COMM_WORLD",
-                              seq=core.next_seq("MPI_COMM_WORLD", "allreduce"),
-                              nbytes=4096)
+                with core.span("api", "allreduce", comm="MPI_COMM_WORLD",
+                               seq=core.next_seq("MPI_COMM_WORLD",
+                                                 "allreduce"),
+                               nbytes=4096):
+                    core.instant("coll", "tuned_decision", coll="allreduce",
+                                 algorithm="psum")
+                    with core.span("coll", "allreduce", provider="han"):
+                        with core.span("dcn", "send", nbytes=4096,
+                                       peer="peer", proto="eager"):
+                            pass
             p = os.path.join(tmp, f"trace.{rank}.json")
             chrome.dump(p, pid=rank)
             paths.append(p)
