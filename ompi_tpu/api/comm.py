@@ -113,13 +113,18 @@ class Comm(PersistentP2PMixin):
         #: touches this comm (zero-cost fast path: one attribute test)
         self._ft = None
         #: fast-path dispatch cache: (slot, op, shape, dtype, …) →
-        #: (mca context, store version, compiled callable)
+        #: (mca context, store version, compiled callable, its
+        #: recycling variant or None)
         self._fast: dict[tuple, tuple] = {}
         #: per-slot last-signature identity cache in FRONT of _fast:
-        #: (op, shape, dtype, ctx, version, fn).  Hits when the caller
-        #: reuses the same buffer signature (training loops do), with
-        #: pure `is` compares — no tuple hash on the hot loop.
+        #: (op, root, shape, dtype, ctx, version, fn, recycling fn,
+        #: signature).  Hits when the caller reuses the same buffer
+        #: signature (training loops do), with pure `is` compares — no
+        #: tuple hash on the hot loop.
         self._hot: dict[str, tuple] = {}
+        #: the arena's spare pool of this comm: signature → its latest
+        #: device-path result (see mesh/arena.py)
+        self._spares: dict[tuple, tuple] = {}
         #: last sharding object accepted by _stage (identity fast path)
         self._ok_sharding = None
 
@@ -329,6 +334,7 @@ class Comm(PersistentP2PMixin):
         self._coll = None
         self._fast.clear()
         self._hot.clear()  # freed comms must not serve the hot path
+        self.mesh.arena.drop_spares(self._spares)
         self._freed = True
 
     # -- buffer staging -------------------------------------------------
@@ -391,10 +397,17 @@ class Comm(PersistentP2PMixin):
     # the zero-per-call-setup hot loop of SURVEY.md §3.3 (VERDICT r1 #1).
 
     def _fast_fn(self, slot: str, base: str, key: tuple, args: tuple,
-                 donate: bool = False, sp=None):
-        """Cached-or-resolved compiled callable for this call signature,
-        or None when the winning module exposes no resolver (host/
-        monitoring modules) — then the caller takes the table path.
+                 donate: bool = False, sp=None, recycle: bool = False):
+        """Cached-or-resolved compiled programs for this call signature,
+        as the cache entry ``(ctx, store version, program, recycling
+        variant)``, or None when the winning module exposes no resolver
+        (host/monitoring modules) — then the caller takes the table path.
+
+        ``recycle``: a device-buffer call — resolve, in the same
+        resolution, the variant that writes into a donated dropped
+        result (None where the module gives none).  Every caller that
+        shares a key with ``_coll_call`` passes it, so the entry always
+        holds the variant there.
 
         ``donate``: the input is a framework-staged buffer this call
         owns — resolve the arena (donating) program variant if the
@@ -411,7 +424,7 @@ class Comm(PersistentP2PMixin):
             if ent[0] is ctx and ent[1] == ctx.store.version:
                 if spc._attached:  # inlined flag test: this IS the hot loop
                     spc.inc(slot)
-                return ent[2]
+                return ent
         except KeyError:
             pass
         if ctx is None:
@@ -423,17 +436,36 @@ class Comm(PersistentP2PMixin):
         if donate:
             donate = bool(ctx.store.get("accelerator_tpu_donate_staged", True))
         if sp is None:
-            fn = resolve(base, *args, donate=donate)
+            fn, rfn = self._resolve(resolve, base, args, donate, recycle)
         else:
             with sp.child("coll", "resolve"):
-                fn = resolve(base, *args, donate=donate)
+                fn, rfn = self._resolve(resolve, base, args, donate, recycle)
         if fn is None:
             return None
         if len(self._fast) > 4096:  # user-op churn backstop
             self._fast.clear()
-        self._fast[key] = (ctx, ver, fn)
+        ent = self._fast[key] = (ctx, ver, fn, rfn)
         spc.inc(slot)
-        return fn
+        return ent
+
+    @staticmethod
+    def _resolve(resolve, base: str, args: tuple, donate: bool,
+                 recycle: bool):
+        fn = resolve(base, *args, donate=donate)
+        if fn is None or not recycle:
+            return fn, None
+        return fn, resolve(base, *args, recycle=True)
+
+    def _recycled(self, fn, rfn, sig: tuple, x, sp=None):
+        """Run a blocking device-buffer call through the arena's spare
+        pool (``HbmArena.run_recycled``): into the signature's dropped
+        previous result where it qualifies, else a fresh allocation.
+        ``sp``: the open api span, which gets the arg ``recycled``."""
+        out, recycled = self.mesh.arena.run_recycled(
+            self._spares, sig, fn, rfn, x)
+        if sp is not None:
+            sp.args["recycled"] = int(recycled)
+        return out
 
     def _ft_guard(self) -> None:
         """The ULFM collective guard. Exactly three call sites —
@@ -459,21 +491,29 @@ class Comm(PersistentP2PMixin):
                            nbytes=spc.payload_nbytes(x))
 
     def _dispatch(self, slot: str, key: tuple, args: tuple, host: bool,
-                  sp=None):
+                  sp=None, recycle: bool = False):
         """Run one blocking collective through the compiled fast path,
-        else the coll table.  ``sp``: a traced caller's open api span."""
+        else the coll table.  ``sp``: a traced caller's open api span.
+        ``recycle``: a device-buffer call of ``_coll_call`` — its
+        result may go into the signature's dropped previous one."""
         self._ft_guard()
         if _trace._enabled:
-            return self._dispatch_traced(slot, key, args, host, sp)
+            return self._dispatch_traced(slot, key, args, host, sp, recycle)
         # host inputs were staged into a buffer this call owns → the
         # arena's donating program variant may consume it (key carries
         # the flag so host/device callers never share a cache entry)
-        fn = self._fast_fn(slot, slot, key + (host,), args, donate=host)
-        out = fn(args[0]) if fn is not None else self.coll.lookup(slot)(*args)
+        ent = self._fast_fn(slot, slot, key + (host,), args, donate=host,
+                            recycle=recycle)
+        if ent is None:
+            out = self.coll.lookup(slot)(*args)
+        elif recycle and ent[3] is not None:
+            out = self._recycled(ent[2], ent[3], key, args[0])
+        else:
+            out = ent[2](args[0])
         return self.mesh.stage_out(out) if host else out
 
     def _dispatch_traced(self, slot: str, key: tuple, args: tuple,
-                         host: bool, sp):
+                         host: bool, sp, recycle: bool = False):
         """_dispatch with tracing on, inside the caller's api span or
         one of its own; ``coll.launch`` covers the compiled program's
         call."""
@@ -481,13 +521,17 @@ class Comm(PersistentP2PMixin):
         if own:
             sp = self._api_span(slot, args[0])
         try:
-            fn = self._fast_fn(slot, slot, key + (host,), args, donate=host,
-                               sp=sp)
-            if fn is None:
+            ent = self._fast_fn(slot, slot, key + (host,), args,
+                                donate=host, sp=sp, recycle=recycle)
+            if ent is None:
                 out = self.coll.lookup(slot)(*args)
             else:
                 with sp.child("coll", "launch"):
-                    out = fn(args[0])
+                    if recycle and ent[3] is not None:
+                        out = self._recycled(ent[2], ent[3], key, args[0],
+                                             sp)
+                    else:
+                        out = ent[2](args[0])
             return self.mesh.stage_out(out) if host else out
         finally:
             if own:
@@ -499,18 +543,21 @@ class Comm(PersistentP2PMixin):
         callable as the blocking slot (shared key), wrapped in an
         ArrayRequest (async XLA dispatch ↔ libnbc schedule)."""
         self._ft_guard()
+        # its results never enter the spare pool; ``recycle`` only keeps
+        # the entry it shares with the blocking slot complete
         if _trace._enabled:
             with self._api_span(slot, args[0]) as sp:
-                fn = self._fast_fn(slot, base, key + (host,), args,
-                                   donate=host, sp=sp)
-                if fn is None:
+                ent = self._fast_fn(slot, base, key + (host,), args,
+                                    donate=host, sp=sp, recycle=not host)
+                if ent is None:
                     req = self.coll.lookup(slot)(*args)
                 else:
                     with sp.child("coll", "launch"):
-                        req = ArrayRequest(fn(args[0]))
+                        req = ArrayRequest(ent[2](args[0]))
             return _wrap_unstage(req, self, host)
-        fn = self._fast_fn(slot, base, key + (host,), args, donate=host)
-        req = (ArrayRequest(fn(args[0])) if fn is not None
+        ent = self._fast_fn(slot, base, key + (host,), args, donate=host,
+                            recycle=not host)
+        req = (ArrayRequest(ent[2](args[0])) if ent is not None
                else self.coll.lookup(slot)(*args))
         return _wrap_unstage(req, self, host)
 
@@ -539,7 +586,10 @@ class Comm(PersistentP2PMixin):
             ):
                 if spc._attached:
                     spc.inc(slot)
-                return c[6](x)
+                if c[7] is None:
+                    return c[6](x)
+                return self.mesh.arena.run_recycled(
+                    self._spares, c[8], c[6], c[7], x)[0]
         return self._coll_miss(slot, x, depth, op, root)
 
     def _coll_call_traced(self, slot: str, x, depth: int, op: Op | None,
@@ -547,8 +597,9 @@ class Comm(PersistentP2PMixin):
         """_coll_call with tracing on: one api span covers the whole
         call, from the entry on; its ``hot`` arg says whether the
         last-signature cache served it (the same test as
-        _coll_call's), and ``coll.launch`` covers the compiled program's
-        call."""
+        _coll_call's), ``recycled`` whether the result went into a
+        dropped previous one, and ``coll.launch`` covers the compiled
+        program's call."""
         with self._api_span(slot, x) as sp:
             if (
                 self._ft is None
@@ -566,8 +617,12 @@ class Comm(PersistentP2PMixin):
                         spc.inc(slot)
                     sp.args["hot"] = 1
                     with sp.child("coll", "launch"):
-                        return c[6](x)
+                        if c[7] is None:
+                            sp.args["recycled"] = 0
+                            return c[6](x)
+                        return self._recycled(c[6], c[7], c[8], x, sp)
             sp.args["hot"] = 0
+            sp.args["recycled"] = 0
             return self._coll_miss(slot, x, depth, op, root, sp)
 
     def _coll_miss(self, slot: str, x, depth: int, op: Op | None,
@@ -583,12 +638,12 @@ class Comm(PersistentP2PMixin):
         key = (slot, op, root, xd.shape, xd.dtype)
         args = (xd,) + ((op,) if op is not None else ()) \
             + ((root,) if root is not None else ())
-        out = self._dispatch(slot, key, args, host, sp)
+        out = self._dispatch(slot, key, args, host, sp, recycle=not host)
         if not host:
             ent = self._fast.get(key + (False,))
             if ent is not None:
                 self._hot[slot] = (op, root, xd.shape, xd.dtype,
-                                   ent[0], ent[1], ent[2])
+                                   ent[0], ent[1], ent[2], ent[3], key)
         return out
 
     def allreduce(self, x, op: Op = SUM):

@@ -313,7 +313,8 @@ class TunedCollModule(CollModule):
         wrapper.__name__ = f"tuned_{slot}"
         return wrapper
 
-    def resolve(self, base: str, *args, donate: bool = False):
+    def resolve(self, base: str, *args, donate: bool = False,
+                recycle: bool = False):
         """Fast-path resolution: run the decision once for this call
         signature, then hand the forced choice to the inner module's
         resolver.  The compiled callable the api layer caches therefore
@@ -321,7 +322,8 @@ class TunedCollModule(CollModule):
         (the cache keys on the store version)."""
         overrides = self._decide(base, args, {})
         with self.inner.forced(**overrides):
-            return self.inner.resolve(base, *args, donate=donate)
+            return self.inner.resolve(base, *args, donate=donate,
+                                      recycle=recycle)
 
     def _decide(self, coll: str, args, kwargs) -> dict[str, int]:
         var_enum = _ALGO_VAR.get(coll)

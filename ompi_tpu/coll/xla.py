@@ -165,7 +165,13 @@ class XlaCollModule(CollModule):
     # construction) happens ONCE per distinct call signature, matching
     # the reference's zero-setup hot loop (SURVEY.md §3.3).
 
-    def resolve(self, base: str, *args, donate: bool = False):
+    def resolve(self, base: str, *args, donate: bool = False,
+                recycle: bool = False):
+        """The compiled program of ``base`` for these arguments.
+        ``recycle=True``: its variant that writes the result into a
+        donated receive buffer (see ``_recycling_fn``), or None."""
+        if recycle:
+            return self._recycling_fn(base, args)
         if base == "allreduce":
             return self._allreduce_fn(args[0], args[1], donate)
         if base == "bcast":
@@ -190,6 +196,32 @@ class XlaCollModule(CollModule):
         if base == "exscan":
             return self._scan_fn(args[0], args[1], True, donate)
         return None
+
+    def _recycling_fn(self, base: str, args: tuple):
+        """``(x, recv) -> out``: the very program ``resolve(base, *args)``
+        gives (the same algorithm, a decision layer's forced choice
+        included), with ``recv`` donated and kept (``keep_unused``) so
+        that XLA writes the result into it instead of allocating.  The
+        api passes a dropped result of the same call signature as
+        ``recv``.  None unless the program's output has ``x``'s shape,
+        dtype and sharding (every ``_spmd`` program writes the comm's
+        rank sharding): decided from the shapes alone."""
+        fn = self.resolve(base, *args)
+        x = args[0]
+        if fn is None:
+            return None
+        out = jax.eval_shape(fn, x)
+        if (out.shape != x.shape or out.dtype != x.dtype
+                or not x.sharding.is_equivalent_to(
+                    self.comm.mesh.rank_sharding(), x.ndim)):
+            return None
+
+        def recycled(v, recv):
+            return fn(v)
+
+        return self._compiled(
+            ("recycle", fn),
+            lambda: jax.jit(recycled, donate_argnums=1, keep_unused=True))
 
     # ==================================================================
     # allreduce

@@ -47,7 +47,7 @@ class CommMesh:
         from .arena import HbmArena
 
         #: staging manager (mpool/rcache analog — SURVEY.md §2.3)
-        self.arena = HbmArena()
+        self.arena = HbmArena(self.devices)
 
     @property
     def size(self) -> int:

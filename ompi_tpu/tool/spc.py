@@ -39,7 +39,7 @@ _BASE_KNOWN = (
     "put", "put_bytes", "get", "get_bytes", "accumulate",
     "file_write_bytes", "file_read_bytes",
     "arena_stage_in", "arena_stage_bytes", "arena_donations",
-    "arena_pool_alloc",
+    "arena_pool_alloc", "recycle_hits", "recycle_misses",
 )
 
 _known_cache: tuple[str, ...] | None = None

@@ -7,6 +7,8 @@ input's HBM for the output — one buffer per call, not two); user jax
 arrays are NEVER donated (MPI preserves sendbuf).
 """
 
+import weakref
+
 import numpy as np
 import pytest
 
@@ -162,3 +164,218 @@ def test_ibarrier_releases_token_on_completion(world):
     # tokens cycled through the pool; at most one fresh alloc for the
     # burst of 3 concurrent tokens beyond the pooled one
     assert s1["pool_hits"] > s0["pool_hits"]
+
+
+# -- dropped results of the device path (spares) --------------------------
+# Blocking allreduce/bcast/alltoall on device buffers keep their latest
+# result; the next call of the signature writes into it when nobody
+# else holds it.  The CPU runtime may or may not reuse the memory, so
+# these check values, is_deleted() and the counters.
+
+RECYCLED = ["allreduce", "bcast", "alltoall"]
+
+
+def _dev_input(comm, coll, seed, width=5):
+    import jax
+
+    n = comm.size
+    shape = (n, n, width) if coll == "alltoall" else (n, width)
+    x = np.random.RandomState(seed).randn(*shape).astype(np.float32)
+    return x, jax.device_put(x, comm.mesh.rank_sharding())
+
+
+def _call(comm, coll, x):
+    if coll == "allreduce":
+        return comm.allreduce(x, SUM)
+    if coll == "bcast":
+        return comm.bcast(x, root=1)
+    return comm.alltoall(x)
+
+
+def _want(coll, xh):
+    if coll == "allreduce":
+        return np.broadcast_to(xh.sum(0, dtype=np.float32), xh.shape)
+    if coll == "bcast":
+        return np.broadcast_to(xh[1], xh.shape)
+    return np.swapaxes(xh, 0, 1)
+
+
+def _delta(arena, s0):
+    s1 = arena.stats()
+    return (s1["recycle_hits"] - s0["recycle_hits"],
+            s1["recycle_misses"] - s0["recycle_misses"])
+
+
+@pytest.fixture()
+def comm(world):
+    """A comm over all of world's ranks on a mesh of its own, so its
+    arena's counters and bytes see this test's calls alone."""
+    from ompi_tpu.api.group import Group
+
+    c = world.create_group(Group(list(range(world.size))))
+    yield c
+    if not c._freed:
+        c.free()
+
+
+@pytest.mark.parametrize("coll", RECYCLED)
+def test_dropped_result_is_recycled(comm, coll):
+    from ompi_tpu.tool import spc
+
+    arena = comm.mesh.arena
+    xh, x = _dev_input(comm, coll, seed=31)
+    xh2, x2 = _dev_input(comm, coll, seed=32)
+    s0 = arena.stats()
+    out = _call(comm, coll, x)
+    np.testing.assert_allclose(np.asarray(out), _want(coll, xh), rtol=1e-6)
+    first = weakref.ref(out)  # watches it without holding it
+    del out
+    assert _delta(arena, s0) == (0, 1)  # nothing to recycle yet
+    spc.attach(True)
+    try:
+        spc.reset()
+        for _ in range(3):  # hot hits, each into the last call's result
+            out = _call(comm, coll, x2)
+            np.testing.assert_allclose(np.asarray(out), _want(coll, xh2),
+                                       rtol=1e-6)
+            del out
+        assert spc.get("recycle_hits") == 3
+        assert spc.get("recycle_misses") == 0
+    finally:
+        spc.attach(False)
+        spc.reset()
+    assert _delta(arena, s0) == (3, 1)
+    # it became the next call's output; a later one freed it
+    assert first() is None or first().is_deleted()
+    # the send buffers are intact
+    for xd, xhost in ((x, xh), (x2, xh2)):
+        assert not xd.is_deleted()
+        np.testing.assert_array_equal(np.asarray(xd), xhost)
+
+
+@pytest.mark.parametrize("holder", ["variable", "container", "shard_view",
+                                    "addressable_data"])
+@pytest.mark.parametrize("coll", RECYCLED)
+def test_held_result_is_never_recycled(comm, coll, holder):
+    arena = comm.mesh.arena
+    xh, x = _dev_input(comm, coll, seed=41)
+    xh2, x2 = _dev_input(comm, coll, seed=42)
+    out = _call(comm, coll, x)
+    want = _want(coll, xh)
+    if holder == "variable":
+        kept, read = out, (lambda: np.asarray(kept))
+    elif holder == "container":
+        kept = {"r": [out]}
+        read = lambda: np.asarray(kept["r"][0])  # noqa: E731
+    elif holder == "shard_view":
+        kept = out.addressable_shards[2].data
+        read, want = (lambda: np.asarray(kept)), want[2:3]
+    else:
+        kept = out.addressable_data(3)
+        read, want = (lambda: np.asarray(kept)), want[3:4]
+    del out
+    s0 = arena.stats()
+    for _ in range(3):
+        res = _call(comm, coll, x2)
+        np.testing.assert_allclose(np.asarray(res), _want(coll, xh2),
+                                   rtol=1e-6)
+        del res
+    # the held result was left alone; the two later results recycled
+    assert _delta(arena, s0) == (2, 1)
+    held = kept if holder != "container" else kept["r"][0]
+    assert not held.is_deleted()
+    np.testing.assert_allclose(read(), want, rtol=1e-6)
+
+
+@pytest.mark.parametrize("path", ["host_buffer", "allgather",
+                                  "reduce_scatter_block", "iallreduce",
+                                  "allreduce_init"])
+def test_other_paths_never_recycle(comm, path):
+    """Host buffers, shape-changing collectives and the non-blocking and
+    persistent families allocate their results as before: nothing enters
+    the pool, nothing is counted, and no result is consumed."""
+    arena = comm.mesh.arena
+    n = comm.size
+    xh, x = _dev_input(comm, "allreduce", seed=51)
+    blocks = np.random.RandomState(52).randn(n, n, 3).astype(np.float32)
+    if path == "host_buffer":
+        run = lambda: comm.allreduce(xh, SUM)  # noqa: E731
+        want = _want("allreduce", xh)
+    elif path == "allgather":
+        run = lambda: comm.allgather(x)  # noqa: E731
+        want = np.broadcast_to(xh, (n,) + xh.shape)
+    elif path == "reduce_scatter_block":
+        xb = comm.mesh.stage_in(blocks)
+        run = lambda: comm.reduce_scatter_block(xb, SUM)  # noqa: E731
+        want = blocks.sum(0)
+    elif path == "iallreduce":
+        run = lambda: comm.iallreduce(x, SUM).wait()  # noqa: E731
+        want = _want("allreduce", xh)
+    else:
+        req = comm.allreduce_init(x, SUM)
+        run = lambda: req.start().wait()  # noqa: E731
+        want = _want("allreduce", xh)
+    s0 = arena.stats()
+    for _ in range(3):  # each result dropped before the next call
+        np.testing.assert_allclose(np.asarray(run()), want, rtol=1e-5)
+    first = run()
+    again = run()
+    assert _delta(arena, s0) == (0, 0)
+    assert comm._spares == {}
+    assert not getattr(first, "is_deleted", lambda: False)()
+    np.testing.assert_allclose(np.asarray(first), np.asarray(again))
+    assert not x.is_deleted()
+    if path == "allgather":
+        assert comm._hot["allgather"][7] is None  # no variant: shape differs
+
+
+def test_free_releases_the_spares(comm):
+    arena = comm.mesh.arena
+    _, x = _dev_input(comm, "allreduce", seed=61, width=9)
+    b0 = arena.stats()["spare_bytes"]
+    comm.allreduce(x, SUM)
+    assert len(comm._spares) == 1
+    assert arena.stats()["spare_bytes"] - b0 == x.nbytes // comm.size
+    comm.free()
+    assert comm._spares == {}
+    assert arena.stats()["spare_bytes"] == b0
+
+
+def test_spare_pool_evicts_least_recently_used(comm, monkeypatch):
+    """Past the signature cap the oldest signature's spare goes, and its
+    next call allocates; the newer ones still recycle."""
+    from ompi_tpu.mesh import arena as arena_mod
+
+    monkeypatch.setattr(arena_mod, "_SPARE_CAP", 2)
+    arena = comm.mesh.arena
+    xs = [_dev_input(comm, "allreduce", seed=70 + w, width=w)[1]
+          for w in (3, 4, 6)]
+    for x in xs:
+        comm.allreduce(x, SUM)
+    assert [k[3] for k in comm._spares] == [x.shape for x in xs[1:]]
+    s0 = arena.stats()
+    comm.allreduce(xs[0], SUM)  # evicted: allocates
+    assert _delta(arena, s0) == (0, 1)
+    comm.allreduce(xs[2], SUM)  # kept: recycles
+    assert _delta(arena, s0) == (1, 1)
+
+
+def test_spare_bytes_bound_evicts(comm):
+    """An arena whose bytes bound holds one result per chip keeps only
+    the latest signature's, and the pinned bytes stay under it."""
+    arena = comm.mesh.arena
+    small = _dev_input(comm, "allreduce", seed=81, width=16)[1]
+    large = _dev_input(comm, "allreduce", seed=82, width=24)[1]
+    per_chip = large.nbytes // comm.size
+    prev, arena.spare_limit = arena.spare_limit, per_chip
+    try:
+        comm.allreduce(small, SUM)
+        comm.allreduce(large, SUM)
+        assert [k[3] for k in comm._spares] == [large.shape]
+        assert arena.stats()["spare_bytes"] == per_chip
+        s0 = arena.stats()
+        comm.allreduce(small, SUM)
+        assert _delta(arena, s0) == (0, 1)
+        assert [k[3] for k in comm._spares] == [small.shape]
+    finally:
+        arena.spare_limit = prev

@@ -278,3 +278,97 @@ def test_schedule_cache_capacity_bounded():
         assert c.stats()["sched_cache_misses"] == 11
     finally:
         store.set("coll_sched_cache_max", 256)
+
+
+@pytest.mark.parametrize("coll,var,algo", [
+    ("allreduce", "coll_xla_reproducible", 1),
+    ("bcast", "coll_xla_bcast_algorithm", 2),      # binomial
+    ("alltoall", "coll_xla_alltoall_algorithm", 2),  # pairwise
+])
+def test_recycled_result_uses_the_forced_algorithm(world, coll, var, algo):
+    """A dropped device-path result is reused as the next call's output
+    buffer by a variant of the very program the cache resolved, the
+    forced algorithm included: the recycled answers are bit for bit the
+    plain ones, and rank-ordered where the order was forced."""
+    import jax
+
+    store = mca.default_context().store
+    shape = (N, 7) if coll == "alltoall" else (11,)
+    x = (rank_data(shape, np.float32, seed=23) * 1e3).astype(np.float32)
+    xd = jax.device_put(x, world.mesh.rank_sharding())
+    run = {"allreduce": lambda: world.allreduce(xd, SUM),
+           "bcast": lambda: world.bcast(xd, root=2),
+           "alltoall": lambda: world.alltoall(xd)}[coll]
+    arena = world.mesh.arena
+    store.set(var, algo)
+    try:
+        plain = np.asarray(run())  # result dropped at once
+        h0 = arena.stats()["recycle_hits"]
+        again = [np.asarray(run()) for _ in range(2)]
+        assert arena.stats()["recycle_hits"] == h0 + 2
+    finally:
+        store.set(var, 0)
+    for out in again:
+        np.testing.assert_array_equal(out, plain)
+    if coll == "allreduce":
+        np.testing.assert_array_equal(plain[0], ordered_reduce_np(x, SUM))
+    elif coll == "bcast":
+        np.testing.assert_array_equal(plain, np.broadcast_to(x[2], x.shape))
+    else:
+        np.testing.assert_array_equal(plain, np.swapaxes(x, 0, 1))
+    assert not xd.is_deleted()
+    np.testing.assert_array_equal(np.asarray(xd), x)
+
+
+def test_api_span_says_whether_the_call_recycled(world):
+    """Tracing on: the api span's ``recycled`` arg is 0 for the cold call
+    and for a call whose last result the caller still holds, 1 where the
+    call wrote into the dropped last result."""
+    import jax
+    from ompi_tpu.trace import core as trace
+
+    xd = jax.device_put(rank_data((29,), np.float32, seed=24),
+                        world.mesh.rank_sharding())
+    trace.reset()
+    trace.enable(True)
+    try:
+        jax.block_until_ready(world.allreduce(xd, SUM))   # cold
+        jax.block_until_ready(world.allreduce(xd, SUM))   # into the 1st
+        held = world.allreduce(xd, SUM)                   # into the 2nd
+        jax.block_until_ready(world.allreduce(xd, SUM))   # 3rd is held
+        args = [e[7] for e in trace.events()
+                if e[0] == "X" and (e[3], e[4]) == ("api", "allreduce")]
+    finally:
+        trace.enable(False)
+        trace.reset()
+    assert [a["hot"] for a in args] == [0, 1, 1, 1]
+    assert [a["recycled"] for a in args] == [0, 1, 1, 0]
+    assert not held.is_deleted()
+
+
+def test_refused_donation_falls_back_to_the_plain_program(world):
+    """Where the runtime refuses to take the dropped result as the
+    output buffer, the call runs the plain program, answers right and
+    counts a miss."""
+    import jax
+
+    x = rank_data((19,), np.float32, seed=25)
+    xd = jax.device_put(x, world.mesh.rank_sharding())
+    world.allreduce(xd, SUM)  # its result is the spare now
+
+    def refuse(v, recv):
+        raise RuntimeError("donation refused")
+
+    c = world._hot["allreduce"]
+    world._hot["allreduce"] = c[:7] + (refuse,) + c[8:]
+    try:
+        s0 = world.mesh.arena.stats()
+        out = world.allreduce(xd, SUM)
+        s1 = world.mesh.arena.stats()
+    finally:
+        world._hot.pop("allreduce")  # the next call re-installs the real one
+    np.testing.assert_allclose(np.asarray(out),
+                               np.broadcast_to(x.sum(0), x.shape), rtol=1e-5)
+    assert (s1["recycle_hits"], s1["recycle_misses"]) == (
+        s0["recycle_hits"], s0["recycle_misses"] + 1)
+    assert world._spares[c[8]][0] is out

@@ -139,9 +139,12 @@ def test_allreduce_spans_reach_the_profiler_cold_then_hot(world, tmp_path):
     """Tracing on, under a profiler session: a cold and a hot
     ``Comm.allreduce`` each give one ``ompi.api.allreduce`` with
     ``ompi.coll.launch`` inside it; only the cold call resolves, and it
-    builds its program (``ompi.coll.build`` inside the resolve: a shape
-    no earlier test used).  The ring holds the same spans, in its own
-    format, and the Chrome export keys only the api spans."""
+    builds its programs, the plain one and the variant that writes into
+    a dropped result (an ``ompi.coll.build`` each inside the resolve: a
+    shape no earlier test used).  The list keeps the first result, so
+    the hot call cannot write into it (``recycled`` 0).  The ring holds
+    the same spans, in its own format, and the Chrome export keys only
+    the api spans."""
     import jax
 
     x = jax.device_put(np.ones((N, 37), np.float32),
@@ -153,6 +156,7 @@ def test_allreduce_spans_reach_the_profiler_cold_then_hot(world, tmp_path):
     api_evs = [e for e in evs if e[0] == "ompi.api.allreduce"]
     assert [e[3]["seq"] for e in api_evs] == [0, 1]
     assert [e[3]["hot"] for e in api_evs] == [0, 1]
+    assert [e[3]["recycled"] for e in api_evs] == [0, 0]
     assert {e[3]["nbytes"] for e in api_evs} == {N * 37 * 4}
     assert {e[3]["comm"] for e in api_evs} == {world.name}
     for name, s, e, st in api_evs:
@@ -166,9 +170,11 @@ def test_allreduce_spans_reach_the_profiler_cold_then_hot(world, tmp_path):
             assert sorted(kids) == ["ompi.coll.launch", "ompi.coll.resolve"]
             (res,) = [c for c in inside if c[0] == "ompi.coll.resolve"]
             assert "comm" not in res[3]
-            (build,) = [c for c in evs if c[0] == "ompi.coll.build"]
-            assert res[1] <= build[1] and build[2] <= res[2]
-            assert build[3] == {}
+            builds = [c for c in evs if c[0] == "ompi.coll.build"]
+            assert len(builds) == 2
+            for build in builds:
+                assert res[1] <= build[1] and build[2] <= res[2]
+                assert build[3] == {}
 
     ring = [(e[3], e[4], e[6], e[7]) for e in trace.events() if e[0] == "X"]
     assert sorted(f"ompi.{la}.{n}" for la, n, _, _ in ring) == sorted(
@@ -181,7 +187,7 @@ def test_allreduce_spans_reach_the_profiler_cold_then_hot(world, tmp_path):
     assert merge.collective_keys(doc) == [
         (world.name, "allreduce", 0), (world.name, "allreduce", 1)]
     assert trace.span_stats()[("coll", "launch")]["count"] == 2
-    assert trace.span_stats()[("coll", "build")]["count"] == 1
+    assert trace.span_stats()[("coll", "build")]["count"] == 2
 
 
 def test_tracing_off_puts_nothing_on_the_profiler(world, tmp_path):
