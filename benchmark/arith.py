@@ -1,4 +1,6 @@
-"""Byte arithmetic of an allreduce and the table of the chip's peaks.
+"""Byte arithmetic of an allreduce (the model of
+``calls/osu_allreduce.py``) and the table of the chip's peaks, which
+every call module's ``floor_s`` is given.
 
 ``bus_bytes`` follows the OSU/NCCL bus-bandwidth model: an allreduce
 of S bytes per rank over n ranks moves ``2(n-1)/n * S`` through each

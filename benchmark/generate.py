@@ -1,14 +1,16 @@
 """The one traffic generator: a closed loop of blocking calls whose
 message sizes a mix file (``traffic/<name>.json``) lists.
 
-A mix gives ``sizes_bytes`` (bytes per rank), ``repeats_per_cycle``
+A mix gives ``sizes_bytes`` (bytes per rank per call), ``repeats_per_cycle``
 (how often each size comes in one cycle) and ``inputs_per_size`` (how
 many distinct send buffers each size has).  One cycle is
 ``repeats_per_cycle`` rounds, each round every size once in an order
 drawn from the seed: every seed does the same work in another order,
 and no seed's order bunches the large messages together.  The window
 runs cycles back to back.  ``checked_per_size`` positions of each size
-are kept for the comparison with the reference.
+are kept for the comparison with the reference.  What a size must be
+(a whole number of the call's elements), and any key of its own, the
+cell's call module checks.
 """
 
 from __future__ import annotations
@@ -29,8 +31,6 @@ def load_mix(path: Path) -> dict:
         raise ValueError(f"traffic mix {path} lacks {missing}")
     if mix["loop"] != "closed" or mix["callers"] != 1:
         raise ValueError(f"{path}: this generator drives one closed-loop caller")
-    if any(s % 4 or s <= 0 for s in mix["sizes_bytes"]):
-        raise ValueError(f"{path}: sizes must be whole float32 counts")
     return mix
 
 

@@ -1,22 +1,22 @@
 """One run of one cell of ``BENCHMARK.json``: set up, measure, check.
 
 Everything that belongs to a cell is found by name: the cell in
-``BENCHMARK.json``, its configuration in the file that names, its
-traffic in ``benchmark/traffic/<traffic>.json`` and each metric in
-``benchmark/metrics/<metric>.py`` (see ``load_reader``).  A
-configuration's ``buffers`` is ``device`` (OSU ``-d``: send buffers
-staged to the chips once) or ``host`` (numpy in, numpy out, through
-the library's staging on every call).  A cell, a mix or a metric is added
-by adding files and entries, never by editing this one.
+``BENCHMARK.json``, its configuration in the file that names, the
+collective that the configuration's ``benchmark`` names (``load_call``),
+its traffic in ``benchmark/traffic/<traffic>.json`` and each metric in
+``benchmark/metrics/<metric>.py`` (``load_reader``).  A configuration's
+``buffers`` is ``device`` (OSU ``-d``: send buffers staged to the chips
+once) or ``host`` (numpy in, numpy out, through the library's staging
+on every call).  A cell, a collective, a mix or a metric is added by
+adding files and entries, never by editing this one.
 
-The run drives ``Comm.allreduce`` on the world that
-``ompi_tpu.api.init()`` returns, one blocking call after another, as
-``osu_allreduce`` does, in whole cycles of the mix.  ``--trace 0``
-measures for ``seconds`` (to the end of the cycle under way) and
-reports the cell's end-to-end metrics; ``--trace 1`` records a
-profiler trace of the mix's shorter ``trace_seconds`` and reports its
-per-layer metrics.  Both compare sampled results with ``reference``
-after the window.
+The run drives the collective on the world that ``ompi_tpu.api.init()``
+returns, one blocking call after another, as the OSU benchmarks do, in
+whole cycles of the mix.  ``--trace 0`` measures for ``seconds`` (to
+the end of the cycle under way) and reports the cell's end-to-end
+metrics; ``--trace 1`` records a profiler trace of the mix's shorter
+``trace_seconds`` and reports its per-layer metrics.  Both compare
+sampled results with the call module's reference after the window.
 """
 
 from __future__ import annotations
@@ -34,9 +34,10 @@ from types import SimpleNamespace
 import jax
 import numpy as np
 
-from . import generate, reference, trace as trace_mod
+from . import generate, trace as trace_mod
 
 ROOT = Path(__file__).resolve().parent.parent
+WRONG = 1e300  # a host caller handed a device array; finite for JSON
 
 
 class DeviceError(RuntimeError):
@@ -58,12 +59,36 @@ def load_cell(name: str, root: Path = ROOT) -> SimpleNamespace:
     layer = [m for m in spec["per_layer"]
              if name in m.get("workloads", [name] if m["moves"] in moved
                               else [])]
-    return SimpleNamespace(
-        name=name, chips=wl["chips"], root=root,
-        config=json.loads((root / cfg["file"]).read_text()),
-        mix=generate.load_mix(root / "benchmark" / "traffic"
-                              / f"{wl['traffic']}.json"),
-        end_to_end=reported, per_layer=layer)
+    config = json.loads((root / cfg["file"]).read_text())
+    call = load_call(root, config["benchmark"])
+    mix = generate.load_mix(root / "benchmark" / "traffic"
+                            / f"{wl['traffic']}.json")
+    call.validate(mix, config)
+    return SimpleNamespace(name=name, chips=wl["chips"], root=root,
+                           config=config, call=call, mix=mix,
+                           end_to_end=reported, per_layer=layer)
+
+
+def _module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_call(root: Path, benchmark: str):
+    """``benchmark/calls/<benchmark>.py``, all that the harness and the
+    readers know of one collective: ``validate(mix, cfg)``; ``inputs(cfg,
+    mix, seed, n, sharding, on_host)``, as ``inputs[size index][slot]``;
+    ``bind(world, cfg)``, the call the window times; ``error(x, out,
+    cfg)``, the number held to ``cfg["check"]``'s limit; ``bus_bytes(
+    nbytes, n)`` and ``floor_s(nbytes, n, peaks)``, ``None`` where it has
+    no such model; ``DEVICE_OPS``, the opcode prefix of its operations on
+    the chips; ``control(x)``, the stand-in of ``control.py``."""
+    path = root / "benchmark" / "calls" / f"{benchmark}.py"
+    if not path.exists():
+        raise FileNotFoundError(f"no call module {path} for {benchmark!r}")
+    return _module(path, f"_bench_call_{benchmark}")
 
 
 def load_reader(root: Path, metric: str):
@@ -73,11 +98,7 @@ def load_reader(root: Path, metric: str):
     path = root / "benchmark" / "metrics" / f"{metric}.py"
     if not path.exists():
         path = path.with_name(f"{metric.split('.', 1)[0]}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"_bench_metric_{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path, f"_bench_metric_{metric.replace('.', '_')}").read
 
 
 # -- the device ---------------------------------------------------------------
@@ -101,32 +122,6 @@ def memory_peak_bytes() -> int:
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
              for d in jax.local_devices()]
     return int(max(peaks))
-
-
-# -- inputs made from the seed ----------------------------------------------
-
-def _normals(key_data, shapes):
-    keys = jax.random.split(jax.random.wrap_key_data(key_data), len(shapes))
-    return tuple(jax.random.normal(k, s, np.float32)
-                 for k, s in zip(keys, shapes))
-
-
-def make_inputs(mix: dict, seed: int, n: int, sharding, on_host: bool):
-    """``inputs[size index][slot]``: standard normal float32 rank-major
-    (n, count) buffers made on the device in one jitted call from
-    ``seed``; host buffers are copied to numpy before the window."""
-    k = mix["inputs_per_size"]
-    shapes = tuple((n, s // 4) for s in mix["sizes_bytes"] for _ in range(k))
-    key = np.random.SeedSequence(seed).generate_state(2, np.uint32)
-    make = jax.jit(_normals, static_argnums=1,
-                   out_shardings=(sharding,) * len(shapes))
-    flat = list(make(key, shapes))
-    jax.block_until_ready(flat)
-    if on_host:
-        for i, a in enumerate(flat):
-            flat[i] = np.asarray(a)
-            a.delete()
-    return [flat[i * k:(i + 1) * k] for i in range(len(mix["sizes_bytes"]))]
 
 
 # -- compiles inside the window ------------------------------------------------
@@ -187,17 +182,20 @@ def window(call, inputs, sched, keep, seconds: float, annotate: bool):
             return sizes, lats, t1 - t_start, kept
 
 
-def check(kept, sizes_compared: int, n_sizes: int, limit: float,
+def check(call, cfg: dict, kept, sizes_compared: int, n_sizes: int,
           want_host: bool) -> tuple[dict, int, bool]:
-    """Compare each kept (input, result, result was numpy) with the
-    reference; every size of the mix must have been compared.  Returns
-    the numbers compared, each beside its limit, how many results failed,
-    and whether the run is correct."""
-    errs = [reference.WRONG if want_host and not was_numpy
-            else reference.max_err_eps(x, np.asarray(out))
+    """Compare each kept (input, result, result was numpy) with the call
+    module's reference, under the one limit that ``cfg["check"]`` gives
+    beside its ``compared``; every size of the mix must have been
+    compared.  Returns the numbers compared, each beside its limit, how
+    many results failed, and whether the run is correct."""
+    [(name, limit)] = [kv for kv in cfg["check"].items()
+                       if kv[0] != "compared"]
+    errs = [WRONG if want_host and not was_numpy
+            else call.error(x, np.asarray(out), cfg)
             for x, out, was_numpy in kept]  # host callers are owed numpy
     worst = max(errs, default=0.0)
-    return ({"max_err_eps": {"value": worst, "limit": limit},
+    return ({name: {"value": worst, "limit": limit},
              "sizes_compared": {"value": sizes_compared, "limit": n_sizes}},
             sum(e > limit for e in errs),
             worst <= limit and sizes_compared >= n_sizes)
@@ -208,22 +206,21 @@ def check(kept, sizes_compared: int, n_sizes: int, limit: float,
 def run(cell, seed: int, seconds: float, traced: bool, t_process: float,
         call=None, chip_check: bool = True) -> dict:
     """Set up, measure, check; returns the result line's object.
-    ``call`` replaces ``world.allreduce(x, op)`` (the control and the
+    ``call`` replaces the call module's bound call (the control and the
     fault tests use it); ``chip_check=False`` skips the device check."""
     if chip_check:
         require_chips(cell.chips)
     import ompi_tpu.api as api
-    from ompi_tpu import op as ops
 
     world = api.init()
     dev = device_info()
     n = world.size
     cfg, mix = cell.config, cell.mix
     if call is None:
-        op = getattr(ops, cfg["op"])
-        call = lambda x: world.allreduce(x, op)  # noqa: E731
+        call = cell.call.bind(world, cfg)
     on_host = cfg["buffers"] == "host"
-    inputs = make_inputs(mix, seed, n, world.mesh.rank_sharding(), on_host)
+    inputs = cell.call.inputs(cfg, mix, seed, n, world.mesh.rank_sharding(),
+                              on_host)
     sched = generate.cycle(mix, seed)
     keep = set(generate.checked_positions(mix, sched, seed))
     for slots in inputs:  # warm every shape of this mix, and only those
@@ -252,14 +249,15 @@ def run(cell, seed: int, seconds: float, traced: bool, t_process: float,
          for si, sl, o in kept.values()])
     sizes_compared = len({si for si, _, _ in kept.values()})
     del inputs, kept
-    numbers, failed, correct = check(host_kept, sizes_compared,
-                                     len(mix["sizes_bytes"]),
-                                     cfg["check"]["max_err_eps"], on_host)
+    numbers, failed, correct = check(cell.call, cfg, host_kept,
+                                     sizes_compared, len(mix["sizes_bytes"]),
+                                     on_host)
     del host_kept
     t_check = time.perf_counter() - t_check
     ctx = SimpleNamespace(
         n=n, sizes_bytes=mix["sizes_bytes"], calls=sizes, lat_s=lats,
-        window_s=wall, setup_s=setup_s, device_kind=dev["kind"], trace=None)
+        window_s=wall, setup_s=setup_s, device_kind=dev["kind"], trace=None,
+        call=cell.call)
     device = {**dev, "memory_peak_bytes": mem}
     result = {"correct": correct, "attempted": len(sizes), "failed": failed}
     if traced:

@@ -1,18 +1,19 @@
-"""device layer, the all-reduce itself: the least time the chips could
-take for every traced call (``arith.allreduce_floor_s``: the bytes each
-chip sends over its ICI rate, or 2S over HBM bandwidth, whichever is
-larger) over the device time of the all-reduce operations, averaged
-over the chips, in percent (trace)."""
+"""device layer, the collective itself: the least time the chips could
+take for every traced call (the call module's ``floor_s``, from the
+chip's peaks) over the device time of its operations (opcodes that
+start with its ``DEVICE_OPS``), averaged over the chips, in percent
+(trace).  Nothing where the module has no floor."""
 
 from benchmark import arith, trace
 
 
 def read(run):
     spent = run.trace.op_seconds(
-        lambda op: trace.opcode(op).startswith("all-reduce"))
+        lambda op: trace.opcode(op).startswith(run.call.DEVICE_OPS))
     if spent <= 0:
         return None
     pk = arith.peaks(run.device_kind)
-    floor = sum(arith.allreduce_floor_s(run.sizes_bytes[s], run.n, pk)[0]
-                for s in run.calls)
-    return floor / spent * 100.0
+    per_size = [run.call.floor_s(s, run.n, pk) for s in run.sizes_bytes]
+    if None in per_size:
+        return None
+    return sum(per_size[s] for s in run.calls) / spent * 100.0

@@ -36,12 +36,48 @@ def tiny_root(tmp_path):
     return tmp_path
 
 
+def add_alltoall(root):
+    """``device_alltoall`` added to ``root`` by files alone, as a later
+    change would add a deployment: the call module
+    ``benchmark/calls/osu_alltoall.py`` (``fixtures/osu_alltoall.py``),
+    its config (device buffers, float32, compared bit for bit), its mix
+    and its entries in BENCHMARK.json."""
+    shutil.copy(Path(__file__).parent / "fixtures" / "osu_alltoall.py",
+                root / "benchmark" / "calls" / "osu_alltoall.py")
+    cfg = {"name": "osu_alltoall_device", "benchmark": "osu_alltoall",
+           "dtype": "float32", "buffers": "device", "chips": 4, "ranks": 4,
+           "ranks_per_chip": 1,
+           "check": {"compared": "mismatches: elements of any rank's "
+                                 "result that differ bit for bit from "
+                                 "the block transpose", "mismatches": 0}}
+    (root / "benchmark" / "configs" / "osu_alltoall_device.json").write_text(
+        json.dumps(cfg))
+    (root / "benchmark" / "traffic" / "alltoall.json").write_text(json.dumps(
+        {"loop": "closed", "callers": 1, "sizes_bytes": [1024, 16384, 65536],
+         "repeats_per_cycle": 3, "inputs_per_size": 2,
+         "checked_per_size": 1, "trace_seconds": 0.2}))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append(
+        {"name": "osu_alltoall_device", "source": "OSU osu_alltoall -d",
+         "file": "benchmark/configs/osu_alltoall_device.json",
+         "reduced": [], "why": "a collective added by files alone"})
+    spec["workloads"].append(
+        {"name": "device_alltoall", "config": "osu_alltoall_device",
+         "traffic": "alltoall", "chips": 4,
+         "why": "a collective added by files alone"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append("device_alltoall")
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+
+
 @pytest.fixture
 def bench_root(tiny_root):
-    """``tiny_root`` with one more cell added by data alone, as a later
-    change would add it: ``host_large``, osu_allreduce with numpy send
+    """``tiny_root`` with two more cells added by files alone, as a later
+    change would add them: ``host_large``, osu_allreduce with numpy send
     buffers on four ranks (OSU's default host-buffer mode), so every call
-    stages in, reduces across the ranks and stages out."""
+    stages in, reduces across the ranks and stages out; and
+    ``device_alltoall`` (``add_alltoall``)."""
     cfgs = tiny_root / "benchmark" / "configs"
     host = json.loads((cfgs / "osu_allreduce_device.json").read_text())
     host.update(name="osu_allreduce_host", buffers="host")
@@ -58,4 +94,5 @@ def bench_root(tiny_root):
         if m["name"] == "busbw_GBps":
             m["workloads"].append("host_large")
     (tiny_root / "BENCHMARK.json").write_text(json.dumps(spec))
+    add_alltoall(tiny_root)
     return tiny_root

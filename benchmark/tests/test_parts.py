@@ -61,6 +61,16 @@ def test_a_mix_that_is_not_one_closed_loop_is_refused(tmp_path, large):
         generate.load_mix(p)
 
 
+@pytest.mark.parametrize("size", [6, 0, -4])
+def test_an_allreduce_size_that_is_no_float32_count_is_refused(large, size):
+    from benchmark.harness import ROOT, load_call
+
+    call = load_call(ROOT, "osu_allreduce")
+    call.validate(large, {})
+    with pytest.raises(ValueError):
+        call.validate({**large, "sizes_bytes": [4096, size]}, {})
+
+
 # -- bytes and peaks ------------------------------------------------------------
 
 def test_bus_bytes_by_hand():

@@ -3,7 +3,9 @@ past the harness's look for a chip: the data-driven lookup, the window,
 the comparison with the reference, and that a broken path or the
 lower-precision control comes out not correct.  Besides the committed
 cells, ``host_large`` (added by data alone, see ``conftest.bench_root``)
-drives the host-buffer path at four ranks."""
+drives the host-buffer path at four ranks, and ``device_alltoall``
+(added by files alone, see ``conftest.add_alltoall``) another
+collective through its own call module."""
 
 import json
 import os
@@ -14,12 +16,13 @@ import time
 import numpy as np
 import pytest
 
-from benchmark import control, harness
+from benchmark import harness
 
 ROOT = harness.ROOT
-CELLS = [w["name"] for w in
-         json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
-CELLS += ["host_large"]
+ALLREDUCE = [w["name"] for w in
+             json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
+ALLREDUCE += ["host_large"]
+CELLS = ALLREDUCE + ["device_alltoall"]
 SEED = 2**31 + 4242
 
 
@@ -44,7 +47,7 @@ def test_a_whole_run_is_correct(bench_root, cell, traced):
         assert set(res["metrics"]) <= {m["name"] for m in c.per_layer}
     else:
         assert set(res["metrics"]) == {m["name"] for m in c.end_to_end}
-        assert "setup_s" in res["metrics"]
+        assert {"busbw_GBps", "setup_s"} <= set(res["metrics"])
 
 
 def _world_call():
@@ -91,16 +94,48 @@ def _answer_altered(x):
     return out.at[-1, -1].add(1)
 
 
+def _bf16_control(x):
+    return harness.load_call(ROOT, "osu_allreduce").control(x)
+
+
 @pytest.mark.parametrize("fault", [_no_exchange, _half_left_out,
-                                   _answer_altered, control.bf16_sum],
+                                   _answer_altered, _bf16_control],
                          ids=["no_exchange", "half_left_out",
                               "answer_altered", "bf16_control"])
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", ALLREDUCE)
 def test_a_broken_path_is_not_correct(bench_root, cell, fault):
     _, res = run_cell(bench_root, cell, call=fault)
     assert not res["correct"], res["check"]
     assert res["check"]["max_err_eps"]["value"] > \
         res["check"]["max_err_eps"]["limit"]
+
+
+def _own_block_only(x):
+    """The exchange left out: each rank keeps the block it sends itself,
+    and receives nothing in the others."""
+    import jax.numpy as jnp
+
+    return jnp.eye(x.shape[0], dtype=x.dtype)[:, :, None] * x
+
+
+def _alltoall_altered(x):
+    import ompi_tpu.api as api
+
+    return api.init().alltoall(x).at[-1, 0, -1].add(1)
+
+
+@pytest.mark.parametrize("fault", ["no_exchange", "in_place",
+                                   "answer_altered", "bf16_control"])
+def test_a_broken_alltoall_is_not_correct(bench_root, fault):
+    """Three broken paths and the control of the collective added by
+    files alone: each reads mismatches above its limit of 0."""
+    ctl = harness.load_call(bench_root, "osu_alltoall").control
+    call = {"no_exchange": _own_block_only, "in_place": lambda x: x,
+            "answer_altered": _alltoall_altered, "bf16_control": ctl}[fault]
+    _, res = run_cell(bench_root, "device_alltoall", call=call)
+    assert not res["correct"], res["check"]
+    assert res["check"]["mismatches"]["value"] > 0
+    assert res["check"]["mismatches"]["limit"] == 0
 
 
 def test_a_new_cell_is_found_with_no_code_edit(bench_root):
@@ -135,6 +170,16 @@ def test_a_split_metric_falls_back_to_its_quantity_s_reader():
 def test_an_unknown_cell_is_refused(tiny_root):
     with pytest.raises(KeyError):
         harness.load_cell("no_such_cell", tiny_root)
+
+
+def test_a_config_that_names_no_call_module_is_refused(tiny_root):
+    cfg = tiny_root / "benchmark" / "configs" / "osu_allreduce_device.json"
+    cfg.write_text(json.dumps({**json.loads(cfg.read_text()),
+                               "benchmark": "osu_nothing"}))
+    with pytest.raises(FileNotFoundError) as err:
+        harness.load_cell("device_large", tiny_root)
+    assert str(tiny_root / "benchmark" / "calls" / "osu_nothing.py") in \
+        str(err.value)
 
 
 def test_the_command_refuses_a_host_without_a_tpu():

@@ -5,10 +5,12 @@ chip runs printed from the same traces."""
 
 import gzip
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
 from benchmark import arith, trace
+from benchmark.harness import ROOT, load_call, load_reader
 
 FIXTURES = Path(__file__).resolve().parent / "fixtures"
 
@@ -62,15 +64,16 @@ def test_four_chip_planes_each_run_one_all_reduce_per_call(device_large):
         assert [trace.opcode(n) for _, _, n in ops] == ["all-reduce"] * 10
 
 
-def test_ici_roofline_arithmetic_matches_the_chip_run(device_large):
-    from types import SimpleNamespace
-
-    from benchmark.harness import ROOT, load_reader
-
+def _run(device_large, call):
     sizes = [1 << 20, 4 << 20, 16 << 20, 64 << 20, 256 << 20]
-    run = SimpleNamespace(trace=device_large, n=4, sizes_bytes=sizes,
-                          calls=[i for i in range(5) for _ in range(2)],
-                          device_kind="TPU v5 lite")
+    return SimpleNamespace(trace=device_large, n=4, sizes_bytes=sizes,
+                           calls=[i for i in range(5) for _ in range(2)],
+                           window_s=device_large.window_s(),
+                           device_kind="TPU v5 lite", call=call)
+
+
+def test_ici_roofline_arithmetic_matches_the_chip_run(device_large):
+    run = _run(device_large, load_call(ROOT, "osu_allreduce"))
     roof = load_reader(ROOT, "ici_roofline")(run)
     assert roof == pytest.approx(42.71048351084056)
     assert 0 < roof <= 100
@@ -80,3 +83,14 @@ def test_ici_roofline_arithmetic_matches_the_chip_run(device_large):
     assert load_reader(ROOT, "idle_share.busbw")(run) == pytest.approx(
         47.02417412997405)
     assert device_large.busy_s() == pytest.approx(0.01255772775)
+
+
+@pytest.mark.parametrize("metric", ["ici_roofline", "busbw_GBps"])
+def test_a_call_with_no_model_reports_nothing(device_large, metric):
+    """A call module whose ``floor_s`` and ``bus_bytes`` give None, on a
+    trace that holds its operations: the readers leave the metric out
+    and never read 0."""
+    call = SimpleNamespace(DEVICE_OPS="all-reduce",
+                           floor_s=lambda nbytes, n, pk: None,
+                           bus_bytes=lambda nbytes, n: None)
+    assert load_reader(ROOT, metric)(_run(device_large, call)) is None

@@ -35,7 +35,7 @@ _OPCODE = re.compile(r"(?:^|\s)([a-z][a-z0-9-]*)\(")
 
 def opcode(op: str) -> str:
     """The HLO opcode of an ``XLA Ops`` event, whose name is the
-    instruction's text (``%all-reduce.1 = f32[1,8]{1,0} all-reduce(...)``)."""
+    instruction's text (``%add.1 = f32[1,8]{1,0} add(...)``)."""
     m = _OPCODE.search(op.split(" = ", 1)[-1])
     return m.group(1) if m else op
 
