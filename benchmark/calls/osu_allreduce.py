@@ -7,7 +7,8 @@ configuration names it by its ``benchmark`` key.  Inputs are standard
 normal ``(n, count)`` float32 buffers made from the seed; a result is
 compared with ``reference.max_err_eps``, the float64 fold over ranks;
 the bus bytes and the least time per call are ``arith``'s allreduce
-model; the control is the reference computed in bfloat16.
+model, which gives no bus bytes to one rank; the control is the
+reference computed in bfloat16.
 """
 
 from __future__ import annotations
@@ -19,6 +20,8 @@ from benchmark import arith, reference
 
 #: the HLO opcode prefix of the operation that does the work on the chips
 DEVICE_OPS = "all-reduce"
+#: the library's span around one call (``benchmark/libspans.py``)
+API_SPAN = "ompi.api.allreduce"
 
 
 def validate(mix: dict, cfg: dict) -> None:
@@ -61,8 +64,10 @@ def error(x: np.ndarray, out: np.ndarray, cfg: dict) -> float:
     return reference.max_err_eps(x, out)
 
 
-def bus_bytes(nbytes: int, n: int) -> float:
-    return arith.bus_bytes(nbytes, n)
+def bus_bytes(nbytes: int, n: int) -> float | None:
+    """``2(n-1)/n * S``; None on one rank, where the model gives 0 and
+    an allreduce moves nothing across a link."""
+    return arith.bus_bytes(nbytes, n) if n >= 2 else None
 
 
 def floor_s(nbytes: int, n: int, peaks: dict) -> float:

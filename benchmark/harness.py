@@ -17,6 +17,16 @@ the end of the cycle under way) and reports the cell's end-to-end
 metrics; ``--trace 1`` records a profiler trace of the mix's shorter
 ``trace_seconds`` and reports its per-layer metrics.  Both compare
 sampled results with the call module's reference after the window.
+
+A traced run records two windows of the same schedule, each in its own
+profiler session.  The first, with the library's tracing off as in the
+measured window, gives the readers ``run.trace``: the device's busy
+time, its operations and the ``bench.*`` host spans.  The second, with
+``ompi_tpu.trace`` on, gives them ``run.lib``: that window's trace and
+the library's ``ompi.*`` spans, as ``benchmark/libspans.py`` takes them.
+A deployment's call module names its api span (``API_SPAN``), and its
+per-layer readers read its spans from ``run.lib``: its span numbers
+come by files alone too.
 """
 
 from __future__ import annotations
@@ -34,7 +44,7 @@ from types import SimpleNamespace
 import jax
 import numpy as np
 
-from . import generate, trace as trace_mod
+from . import generate, libspans, trace as trace_mod
 
 ROOT = Path(__file__).resolve().parent.parent
 WRONG = 1e300  # a host caller handed a device array; finite for JSON
@@ -84,7 +94,8 @@ def load_call(root: Path, benchmark: str):
     cfg)``, the number held to ``cfg["check"]``'s limit; ``bus_bytes(
     nbytes, n)`` and ``floor_s(nbytes, n, peaks)``, ``None`` where it has
     no such model; ``DEVICE_OPS``, the opcode prefix of its operations on
-    the chips; ``control(x)``, the stand-in of ``control.py``."""
+    the chips; ``control(x)``, the stand-in of ``control.py``; and, where
+    the library spans its call, ``API_SPAN``, the name of that span."""
     path = root / "benchmark" / "calls" / f"{benchmark}.py"
     if not path.exists():
         raise FileNotFoundError(f"no call module {path} for {benchmark!r}")
@@ -182,6 +193,44 @@ def window(call, inputs, sched, keep, seconds: float, annotate: bool):
             return sizes, lats, t1 - t_start, kept
 
 
+def _start_trace(trace_dir: str) -> None:
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+
+
+def library_window(call, inputs, sched, keep,
+                   seconds: float) -> tuple[str, int]:
+    """The window again, annotated, in a profiler session of its own,
+    with the library's tracing on, so that its ``ompi.*`` spans land in
+    the trace.  It holds the results of ``keep`` as the measured window
+    does (a held result is no spare for the library to reuse) and drops
+    them after.  Returns the trace's directory and the compiles inside
+    the window."""
+    from ompi_tpu import trace as lib_trace
+
+    trace_dir = tempfile.mkdtemp(prefix="bench_trace_lib_")
+    lib_trace.enable(True)
+    try:
+        _start_trace(trace_dir)
+        with CompileCounter() as compiles, \
+                jax.profiler.TraceAnnotation("bench.window"):
+            window(call, inputs, sched, keep, seconds, True)
+        jax.profiler.stop_trace()
+    finally:
+        lib_trace.enable(False)
+    return trace_dir, compiles.count
+
+
+def _read_trace(trace_dir: str, *reducers) -> tuple:
+    """Each of ``reducers`` applied to the profile that the session wrote
+    under ``trace_dir``, which is then deleted."""
+    pd = trace_mod.profile(trace_dir)
+    out = tuple(f(pd) for f in reducers)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return out
+
+
 def check(call, cfg: dict, kept, sizes_compared: int, n_sizes: int,
           want_host: bool) -> tuple[dict, int, bool]:
     """Compare each kept (input, result, result was numpy) with the call
@@ -229,9 +278,7 @@ def run(cell, seed: int, seconds: float, traced: bool, t_process: float,
                 jax.block_until_ready(call(x))
     if traced:
         trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        _start_trace(trace_dir)
         seconds = min(seconds, mix["trace_seconds"])
     setup_s = time.perf_counter() - t_process
     span = (jax.profiler.TraceAnnotation("bench.window") if traced
@@ -241,28 +288,35 @@ def run(cell, seed: int, seconds: float, traced: bool, t_process: float,
                                          traced)
     if traced:
         jax.profiler.stop_trace()
-    mem = memory_peak_bytes()
     t_check = time.perf_counter()
     # one device_get for every array, so their copies overlap
     host_kept = jax.device_get(
         [(inputs[si][sl], o, isinstance(o, np.ndarray))
          for si, sl, o in kept.values()])
     sizes_compared = len({si for si, _, _ in kept.values()})
-    del inputs, kept
+    t_check = time.perf_counter() - t_check
+    del kept  # the results are on the host: the device holds them no more
+    if traced:
+        lib_dir, lib_compiles = library_window(call, inputs, sched, keep,
+                                               seconds)
+    del inputs
+    mem = memory_peak_bytes()
+    t0 = time.perf_counter()
     numbers, failed, correct = check(cell.call, cfg, host_kept,
                                      sizes_compared, len(mix["sizes_bytes"]),
                                      on_host)
     del host_kept
-    t_check = time.perf_counter() - t_check
+    t_check += time.perf_counter() - t0
     ctx = SimpleNamespace(
         n=n, sizes_bytes=mix["sizes_bytes"], calls=sizes, lat_s=lats,
         window_s=wall, setup_s=setup_s, device_kind=dev["kind"], trace=None,
-        call=cell.call)
+        lib=None, call=cell.call)
     device = {**dev, "memory_peak_bytes": mem}
     result = {"correct": correct, "attempted": len(sizes), "failed": failed}
     if traced:
-        ctx.trace = trace_mod.load(trace_dir)
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        [ctx.trace] = _read_trace(trace_dir, trace_mod.from_profile)
+        ctx.lib = _read_trace(lib_dir, trace_mod.from_profile,
+                             libspans.from_profile)
         device["busy_s"] = ctx.trace.busy_s()
         device["window_s"] = ctx.trace.window_s()
         result["breakdown"] = ctx.trace.breakdown()
@@ -275,6 +329,8 @@ def run(cell, seed: int, seconds: float, traced: bool, t_process: float,
         if v is not None:
             values[m["name"]] = {"value": v, "unit": m["unit"]}
     print(f"compiles_in_window {compiles.count}", file=sys.stderr)
+    if traced:
+        print(f"compiles_in_library_window {lib_compiles}", file=sys.stderr)
     print(f"check_seconds {t_check}", file=sys.stderr, flush=True)
     result.update(metrics=values, device=device, check=numbers)
     return result

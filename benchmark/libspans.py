@@ -5,12 +5,23 @@ While ``ompi_tpu``'s tracing is on (``--mca trace_enable 1`` or
 ``ompi_tpu.trace.enable()``), every span it records also lands on the
 profiler's host plane as ``ompi.<layer>.<op>``, with the span's args as
 the event's stats, on the clock of the chips' ``XLA Ops``.  On the
-allreduce fast path a call is one ``ompi.api.allreduce`` (args ``seq``,
-``nbytes``, ``hot``) holding ``ompi.coll.launch`` (the compiled
-program's call) and, when the api's program cache misses,
+device fast path of a collective a call is one ``ompi.api.<op>`` (args
+``seq``, ``nbytes``, ``hot``, ``recycled``) holding ``ompi.coll.launch``
+(the compiled program's call) and, when the api's program cache misses,
 ``ompi.coll.resolve``, which holds ``ompi.coll.build`` when the coll
-program cache builds the program.  Every number here is taken inside
-``bench.window``; each is None where the trace holds no library spans.
+program cache builds the program.
+
+How a deployment's spans reach its readers: ``harness.run`` records,
+after the traced window, a second profiler session of the same schedule
+with the library's tracing on, and hands the readers that window as
+``run.lib``, the ``(tr, lib)`` pair that the functions here take
+(``tr`` from ``trace.from_profile``, ``lib`` from ``from_profile``).
+Which api span is the deployment's is its call module's ``API_SPAN``;
+a module without one gets no span numbers.  So a deployment brings its
+span numbers by files alone: its call module names its span, and a
+reader in ``benchmark/metrics`` calls a function here.  Every number
+here is taken inside ``bench.window``; each is None where the trace
+holds no library spans.
 """
 
 from __future__ import annotations
@@ -22,7 +33,6 @@ import statistics
 from benchmark import trace as trace_mod
 
 LIB_SPAN = "ompi."
-API = "ompi.api.allreduce"
 LAUNCH = "ompi.coll.launch"
 CHILD = "ompi.coll."
 WAIT = "bench.wait"
@@ -43,6 +53,14 @@ def from_profile(pd) -> dict[str, list[tuple[float, float, dict]]]:
     return {k: sorted(v, key=lambda s: s[:2]) for k, v in out.items()}
 
 
+def of_run(run) -> tuple | None:
+    """``(tr, lib, api)`` for a reader: the run's second traced window
+    and its call module's ``API_SPAN``; None where the run has no such
+    window or the module names no api span."""
+    api = getattr(run.call, "API_SPAN", None)
+    return None if run.lib is None or api is None else (*run.lib, api)
+
+
 def _inside(tr: trace_mod.Trace, spans) -> list:
     return [s for s in spans if s[0] >= tr.lo and s[1] <= tr.hi]
 
@@ -51,12 +69,12 @@ def _us(ns: float) -> float:
     return ns / 1e3
 
 
-# -- the four numbers -----------------------------------------------------------
+# -- the per-call numbers ------------------------------------------------------
 
-def api_self_us(tr: trace_mod.Trace, lib: dict) -> float | None:
-    """Median over calls of the api span's time outside its coll
+def api_self_us(tr: trace_mod.Trace, lib: dict, api: str) -> float | None:
+    """Median over calls of the api span ``api``'s time outside its coll
     children: the api layer's own Python."""
-    calls = _inside(tr, lib.get(API, []))
+    calls = _inside(tr, lib.get(api, []))
     if not calls:
         return None
     kids = sorted((s, e) for k, v in lib.items() if k.startswith(CHILD)
@@ -69,12 +87,24 @@ def api_self_us(tr: trace_mod.Trace, lib: dict) -> float | None:
     return _us(statistics.median(selfs))
 
 
-def api_hot_share(tr: trace_mod.Trace, lib: dict) -> float | None:
+def api_hot_share(tr: trace_mod.Trace, lib: dict, api: str) -> float | None:
     """Share of api calls that the last-signature cache served, %."""
-    calls = _inside(tr, lib.get(API, []))
+    calls = _inside(tr, lib.get(api, []))
     if not calls:
         return None
     return 100.0 * sum(1 for *_, st in calls if st.get("hot") == 1) / len(calls)
+
+
+def recycle_hit_share(tr: trace_mod.Trace, lib: dict,
+                      api: str) -> float | None:
+    """Share of api calls whose result went into a dropped earlier one
+    (``recycled`` 1: the arena's spare pool served it), %; None where no
+    call carries the arg."""
+    calls = _inside(tr, lib.get(api, []))
+    if not any("recycled" in st for *_, st in calls):
+        return None
+    return 100.0 * sum(1 for *_, st in calls
+                       if st.get("recycled") == 1) / len(calls)
 
 
 def launch_us(tr: trace_mod.Trace, lib: dict) -> float | None:
@@ -207,9 +237,10 @@ def _wait_side(wait_start, t, calls, starts) -> str:
     return WAIT + (".before_op" if t < starts[j] else ".after_op")
 
 
-def numbers(tr: trace_mod.Trace, lib: dict) -> dict:
-    return {"api_self_us": api_self_us(tr, lib),
-            "api_hot_share": api_hot_share(tr, lib),
+def numbers(tr: trace_mod.Trace, lib: dict, api: str) -> dict:
+    return {"api_self_us": api_self_us(tr, lib, api),
+            "api_hot_share": api_hot_share(tr, lib, api),
+            "recycle_hit_share": recycle_hit_share(tr, lib, api),
             "launch_us": launch_us(tr, lib),
             "launch_to_device_us": launch_to_device_us(tr, lib),
             "clock_offset_us": clock_offset_us(tr, lib)}

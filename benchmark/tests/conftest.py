@@ -21,19 +21,23 @@ import pytest  # noqa: E402
 TINY = {"large": [4096, 65536]}
 
 
-@pytest.fixture
-def tiny_root(tmp_path):
-    """A copy of BENCHMARK.json and the benchmark's data and readers,
-    with every mix cut to a few small sizes."""
-    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
-    shutil.copytree(ROOT / "benchmark", tmp_path / "benchmark",
+def copy_tiny(root):
+    """A copy of BENCHMARK.json and the benchmark's data and readers in
+    ``root``, with every mix cut to a few small sizes."""
+    shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
+    shutil.copytree(ROOT / "benchmark", root / "benchmark",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     for name, sizes in TINY.items():
-        p = tmp_path / "benchmark" / "traffic" / f"{name}.json"
+        p = root / "benchmark" / "traffic" / f"{name}.json"
         mix = json.loads(p.read_text())
         mix.update(sizes_bytes=sizes, repeats_per_cycle=3, trace_seconds=0.2)
         p.write_text(json.dumps(mix))
-    return tmp_path
+    return root
+
+
+@pytest.fixture
+def tiny_root(tmp_path):
+    return copy_tiny(tmp_path)
 
 
 def add_alltoall(root):
@@ -68,6 +72,37 @@ def add_alltoall(root):
     for m in spec["end_to_end"] + spec["per_layer"]:
         if "workloads" in m:
             m["workloads"].append("device_alltoall")
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+
+
+def add_hbm_to_host(root):
+    """``hbm_read`` added to ``root`` by files alone, as a later change
+    would add a one-chip deployment: the call module
+    ``benchmark/calls/hbm_to_host.py`` (``fixtures/hbm_to_host.py``),
+    its config (one rank on one chip, compared bit for bit) and its
+    entries in BENCHMARK.json, on the mix ``large``."""
+    shutil.copy(Path(__file__).parent / "fixtures" / "hbm_to_host.py",
+                root / "benchmark" / "calls" / "hbm_to_host.py")
+    cfg = {"name": "hbm_to_host_one_rank", "benchmark": "hbm_to_host",
+           "dtype": "float32", "buffers": "device", "chips": 1, "ranks": 1,
+           "ranks_per_chip": 1,
+           "check": {"compared": "mismatches: elements of the result that "
+                                 "differ bit for bit from the input",
+                     "mismatches": 0}}
+    (root / "benchmark" / "configs" / "hbm_to_host_one_rank.json").write_text(
+        json.dumps(cfg))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append(
+        {"name": "hbm_to_host_one_rank", "source": "device to host copy",
+         "file": "benchmark/configs/hbm_to_host_one_rank.json",
+         "reduced": [], "why": "a one-rank call added by files alone"})
+    spec["workloads"].append(
+        {"name": "hbm_read", "config": "hbm_to_host_one_rank",
+         "traffic": "large", "chips": 1,
+         "why": "a one-rank call added by files alone"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if m["name"] in ("busbw_GBps", "idle_share.busbw"):
+            m["workloads"].append("hbm_read")
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
 
 

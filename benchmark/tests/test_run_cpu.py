@@ -5,7 +5,9 @@ lower-precision control comes out not correct.  Besides the committed
 cells, ``host_large`` (added by data alone, see ``conftest.bench_root``)
 drives the host-buffer path at four ranks, and ``device_alltoall``
 (added by files alone, see ``conftest.add_alltoall``) another
-collective through its own call module."""
+collective through its own call module.  ``hbm_read`` (added by files
+alone, see ``conftest.add_hbm_to_host``) runs on one rank, in a process
+with one CPU device."""
 
 import json
 import os
@@ -16,7 +18,9 @@ import time
 import numpy as np
 import pytest
 
-from benchmark import harness
+from benchmark import harness, libspans
+
+from conftest import add_hbm_to_host, copy_tiny
 
 ROOT = harness.ROOT
 ALLREDUCE = [w["name"] for w in
@@ -191,3 +195,112 @@ def test_the_command_refuses_a_host_without_a_tpu():
     assert r.returncode != 0
     assert "no TPU" in r.stderr
     assert not any(ln.startswith("{") for ln in r.stdout.splitlines())
+
+
+SPAN_READERS = {"api_self_us.busbw", "launch_us.busbw",
+                "recycle_hit_share.busbw"}
+
+
+def test_a_traced_run_reads_the_library_spans_in_a_second_window(
+        tiny_root, monkeypatch):
+    """The first traced window runs with the library's tracing off and
+    holds no ``ompi.*`` event; the second, in its own profiler session,
+    holds the call module's api spans, which the span readers read.  On
+    the CPU there are no device planes: ``ici_roofline`` and
+    ``idle_share.busbw`` report nothing, as before."""
+    from ompi_tpu import trace as lib_trace
+
+    profiles = []
+    profile = harness.trace_mod.profile
+
+    def keep(trace_dir):
+        profiles.append(profile(trace_dir))
+        return profiles[-1]
+
+    monkeypatch.setattr(harness.trace_mod, "profile", keep)
+    c, res = run_cell(tiny_root, "device_large", traced=True)
+    assert res["correct"], res["check"]
+    assert not lib_trace.enabled()
+    first, second = (libspans.from_profile(pd) for pd in profiles)
+    assert first == {}
+    api = c.call.API_SPAN
+    assert second[api] and second[libspans.LAUNCH]
+    assert set(res["metrics"]) == SPAN_READERS
+    assert {m["name"] for m in c.per_layer} == SPAN_READERS | {
+        "ici_roofline", "idle_share.busbw"}
+    tr = harness.trace_mod.from_profile(profiles[1])
+    in_window = [s for s, e, _ in second[api] if tr.lo <= s and e <= tr.hi]
+    assert len(in_window) == len(tr.spans["bench.call"]) > 0
+    assert res["metrics"]["recycle_hit_share.busbw"]["value"] == \
+        libspans.recycle_hit_share(tr, second, api)
+
+
+ONE_DEVICE = """
+import json, sys, time
+from pathlib import Path
+
+sys.path.insert(0, sys.argv[2])
+from benchmark import harness
+
+root, seed = Path(sys.argv[1]), int(sys.argv[3])
+
+
+def altered(x):
+    out = harness.load_call(root, "hbm_to_host").bind(None, {})(x).copy()
+    out[-1, -1] += 1
+    return out
+
+
+got = {}
+for name, cell, call, traced in [("hbm_read", "hbm_read", None, False),
+                                 ("hbm_read_traced", "hbm_read", None, True),
+                                 ("altered", "hbm_read", altered, False),
+                                 ("allreduce", "device_large", None, False)]:
+    c = harness.load_cell(cell, root)
+    got[name] = harness.run(c, seed, 0.3, traced, time.perf_counter(),
+                            call=call, chip_check=False)
+print(json.dumps(got))
+"""
+
+
+@pytest.fixture(scope="module")
+def one_device(tmp_path_factory):
+    """``hbm_read`` and ``device_large`` in a process with one CPU
+    device: one rank."""
+    root = copy_tiny(tmp_path_factory.mktemp("one_device"))
+    add_hbm_to_host(root)
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=1"}
+    r = subprocess.run([sys.executable, "-c", ONE_DEVICE, str(root),
+                        str(ROOT), str(SEED)],
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-4000:]
+    return json.loads(r.stdout.splitlines()[-1])
+
+
+def test_a_one_rank_call_reports_busbw_and_is_correct(one_device):
+    res = one_device["hbm_read"]
+    assert res["device"]["count"] == 1
+    assert res["correct"] and res["failed"] == 0, res["check"]
+    assert res["check"]["mismatches"] == {"value": 0.0, "limit": 0}
+    assert set(res["metrics"]) == {"busbw_GBps", "setup_s"}
+    assert res["metrics"]["busbw_GBps"]["value"] > 0
+
+
+def test_a_one_rank_call_without_an_api_span_gets_no_span_numbers(
+        one_device):
+    res = one_device["hbm_read_traced"]
+    assert res["correct"], res["check"]
+    assert not set(res["metrics"]) & SPAN_READERS
+
+
+def test_a_one_rank_answer_altered_is_not_correct(one_device):
+    res = one_device["altered"]
+    assert not res["correct"] and res["failed"] > 0
+    assert res["check"]["mismatches"]["value"] > 0
+
+
+def test_an_allreduce_on_one_rank_reports_no_busbw(one_device):
+    res = one_device["allreduce"]
+    assert res["device"]["count"] == 1 and res["correct"], res["check"]
+    assert set(res["metrics"]) == {"setup_s"}

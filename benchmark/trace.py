@@ -208,10 +208,12 @@ def from_profile(pd) -> Trace:
     return Trace(devices, dict(spans), transfers)
 
 
-def load(trace_dir) -> Trace:
+def profile(trace_dir):
+    """The profile that one profiler session wrote under ``trace_dir``,
+    as ``jax.profiler.ProfileData``."""
     import jax
 
     found = sorted(Path(trace_dir).glob("plugins/profile/*/*.xplane.pb"))
     if not found:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    return from_profile(jax.profiler.ProfileData.from_file(str(found[-1])))
+    return jax.profiler.ProfileData.from_file(str(found[-1]))
