@@ -44,7 +44,7 @@ from types import SimpleNamespace
 import jax
 import numpy as np
 
-from . import generate, libspans, trace as trace_mod
+from . import arith, generate, libspans, trace as trace_mod
 
 ROOT = Path(__file__).resolve().parent.parent
 WRONG = 1e300  # a host caller handed a device array; finite for JSON
@@ -309,8 +309,10 @@ def run(cell, seed: int, seconds: float, traced: bool, t_process: float,
     t_check += time.perf_counter() - t0
     ctx = SimpleNamespace(
         n=n, sizes_bytes=mix["sizes_bytes"], calls=sizes, lat_s=lats,
-        window_s=wall, setup_s=setup_s, device_kind=dev["kind"], trace=None,
-        lib=None, call=cell.call)
+        window_s=wall, setup_s=setup_s, device_kind=dev["kind"],
+        ici_links=arith.ici_links([getattr(d, "coords", None)
+                                   for d in jax.devices()]),
+        trace=None, lib=None, call=cell.call)
     device = {**dev, "memory_peak_bytes": mem}
     result = {"correct": correct, "attempted": len(sizes), "failed": failed}
     if traced:

@@ -80,17 +80,33 @@ def test_bus_bytes_by_hand():
 
 
 def test_allreduce_floor_on_v5e_by_hand():
-    pk = arith.peaks("TPU v5 lite")
+    pk = arith.peaks("TPU v5 lite", 2)
+    assert pk["ici_bytes_per_s"] == 100e9  # two links of 50 GB/s
     t, bound = arith.allreduce_floor_s(256 * MiB, 4, pk)
-    # 1.5 * 256 MiB / 200 GB/s beats 2 * 256 MiB / 819 GB/s
-    assert bound == "ici" and t == pytest.approx(402653184 / 200e9)
-    t, bound = arith.allreduce_floor_s(256 * MiB, 1, pk)
+    # 1.5 * 256 MiB / 100 GB/s beats 2 * 256 MiB / 819 GB/s
+    assert bound == "ici" and t == pytest.approx(402653184 / 100e9)
+    t, bound = arith.allreduce_floor_s(256 * MiB, 1,
+                                       arith.peaks("TPU v5 lite", 0))
     assert bound == "hbm" and t == pytest.approx(536870912 / 819e9)
+
+
+@pytest.mark.parametrize("coords, links", [
+    ([(0, 0, 0), (1, 0, 0), (0, 1, 0), (1, 1, 0)], 2),
+    ([(0, 0, 0)], 0),
+    ([(x, y, 0) for x in range(4) for y in range(2)], 2),
+    ([(0, 0, 0), (1, 0, 0)], 1),
+    ([(0, 0, 0), None], None),
+    ([None], None)], ids=["2x2", "one_chip", "4x2", "1x2", "cpu_mixed",
+                          "cpu"])
+def test_ici_links_from_coords(coords, links):
+    """The fewest neighbours at distance 1 that any chip has in the
+    slice: a corner of a 2x2 or a 4x2 has two."""
+    assert arith.ici_links(coords) == links
 
 
 def test_an_unknown_device_kind_has_no_peaks():
     with pytest.raises(KeyError):
-        arith.peaks("TPU v9 imaginary")
+        arith.peaks("TPU v9 imaginary", 2)
 
 
 # -- the reference ---------------------------------------------------------------

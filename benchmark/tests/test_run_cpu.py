@@ -227,7 +227,8 @@ def test_a_traced_run_reads_the_library_spans_in_a_second_window(
     assert second[api] and second[libspans.LAUNCH]
     assert set(res["metrics"]) == SPAN_READERS
     assert {m["name"] for m in c.per_layer} == SPAN_READERS | {
-        "ici_roofline", "idle_share.busbw"}
+        "ici_roofline", "idle_share.busbw", "runtime_launch_us.busbw",
+        "runtime_complete_us.busbw"}
     tr = harness.trace_mod.from_profile(profiles[1])
     in_window = [s for s, e, _ in second[api] if tr.lo <= s and e <= tr.hi]
     assert len(in_window) == len(tr.spans["bench.call"]) > 0

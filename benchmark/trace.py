@@ -10,8 +10,14 @@ with the matching ``...=>IssueEvent=>Done``, which names the chip
 (``chip_id``) and the bytes (``size``).  A chip counts as busy while
 it runs an operation or a copy to or from it is under way.  The
 benchmark's own host spans (``bench.window``, ``bench.call``,
-``bench.wait``) are on the host plane too, on the same clock.  Every
-number is taken inside the ``bench.window`` span.
+``bench.wait``) are on the host plane too, on the same clock, and so
+are the TPU runtime's own events (``RUNTIME``): PJRT's call into the
+program (``PJRT_LoadedExecutable_Execute``), each chip's launch on a
+thread of its own (``TpuLoadedExecutable::ExecuteLaunch``), and each
+chip's completion thread reading the chip's flag that the program has
+ended (``ReadSyncFlag``) and then running the result's callbacks
+(``CompleteCallbacks``).  Every number is taken inside the
+``bench.window`` span.
 """
 
 from __future__ import annotations
@@ -19,6 +25,7 @@ from __future__ import annotations
 import bisect
 import collections
 import re
+import statistics
 from pathlib import Path
 
 DEVICE_PLANE = "/device:TPU:"
@@ -28,6 +35,12 @@ BENCH_SPAN = "bench."
 TRANSFERS = {"tpu::System::TransferToDevice": "h2d",
              "tpu::System::TransferFromDevice": "d2h"}
 DONE = "=>IssueEvent=>Done"
+#: the TPU runtime's host events that split a call's round trip
+EXECUTE = "PJRT_LoadedExecutable_Execute"
+LAUNCH = "TpuLoadedExecutable::ExecuteLaunch"
+SYNC = "ReadSyncFlag"
+COMPLETE = "CompleteCallbacks"
+RUNTIME = (EXECUTE, LAUNCH, SYNC, COMPLETE)
 
 
 _OPCODE = re.compile(r"(?:^|\s)([a-z][a-z0-9-]*)\(")
@@ -87,7 +100,8 @@ class Trace:
 
     def __init__(self, devices: dict[int, list[tuple[float, float, str]]],
                  spans: dict[str, list[tuple[float, float]]],
-                 transfers: list[tuple[float, float, str, int]] = ()):
+                 transfers: list[tuple[float, float, str, int]] = (),
+                 runtime: dict[str, list[tuple[float, float]]] | None = None):
         #: chip -> its operations and its copies, as (start, end, name)
         self.devices = {k: list(v) for k, v in sorted(devices.items())}
         for s, e, direction, chip in transfers:
@@ -96,6 +110,8 @@ class Trace:
             ops.sort()
         self.transfers = sorted(transfers)
         self.spans = {k: sorted(v) for k, v in spans.items()}
+        #: the runtime's events by name (``RUNTIME``), as (start, end)
+        self.runtime = {k: sorted(v) for k, v in (runtime or {}).items()}
         win = self.spans.get("bench.window", [])
         if len(win) != 1:
             raise ValueError(f"want one bench.window span, found {len(win)}")
@@ -153,6 +169,47 @@ class Trace:
                 "idle_gaps": [[n, v / k / 1e9]
                               for n, v in idle.most_common(top)]}
 
+    # -- the runtime's share of each call's round trip -----------------------
+
+    def runtime_calls(self) -> list[dict[str, list[tuple[float, float]]]]:
+        """Per ``bench.call``, the runtime's events that start during it:
+        from its start to the next call's start (the last call's, to the
+        window's end)."""
+        calls = [s for s, _ in self.spans.get("bench.call", [])]
+        out = [{k: [] for k in RUNTIME} for _ in calls]
+        for name in RUNTIME:
+            for s, e in self.runtime.get(name, []):
+                i = bisect.bisect_right(calls, s) - 1
+                if i >= 0 and s < self.hi:
+                    out[i][name].append((s, e))
+        return out
+
+    def runtime_launch_us(self) -> float | None:
+        """Median over calls of the runtime's launch: from the start of
+        the call's first ``EXECUTE`` to the end of its last ``LAUNCH``,
+        on whichever chip's thread ends last.  None where no call holds
+        both."""
+        per_call = [max(e for _, e in c[LAUNCH]) - c[EXECUTE][0][0]
+                    for c in self.runtime_calls() if c[EXECUTE] and c[LAUNCH]]
+        return statistics.median(per_call) / 1e3 if per_call else None
+
+    def runtime_complete_us(self) -> float | None:
+        """Median over calls of the runtime's completion: from the start
+        of the first ``SYNC`` that starts after the call's last
+        ``LAUNCH`` has ended, to the end of the last ``COMPLETE`` that
+        starts after it, on whichever chip's thread ends last.  None
+        where no call holds all three."""
+        per_call = []
+        for c in self.runtime_calls():
+            if not c[LAUNCH]:
+                continue
+            launched = max(e for _, e in c[LAUNCH])
+            syncs = [s for s, _ in c[SYNC] if s >= launched]
+            done = [e for s, e in c[COMPLETE] if s >= launched]
+            if syncs and done:
+                per_call.append(max(done) - syncs[0])
+        return statistics.median(per_call) / 1e3 if per_call else None
+
 
 def _split(gs: float, ge: float, host, ends) -> list[tuple[str, float]]:
     """Share the gap ``[gs, ge]`` out among the host spans (one after
@@ -180,6 +237,7 @@ def _pair(starts, dones):
 
 def from_profile(pd) -> Trace:
     devices, spans = {}, collections.defaultdict(list)
+    runtime = collections.defaultdict(list)
     starts = {d: [] for d in TRANSFERS.values()}
     dones = {d: [] for d in TRANSFERS.values()}
     for plane in pd.planes:
@@ -197,6 +255,9 @@ def from_profile(pd) -> Trace:
                     if name.startswith(BENCH_SPAN):
                         spans[name].append(
                             (e.start_ns, e.start_ns + e.duration_ns))
+                    elif name in RUNTIME:
+                        runtime[name].append(
+                            (e.start_ns, e.start_ns + e.duration_ns))
                     elif name in TRANSFERS:
                         starts[TRANSFERS[name]].append(e.start_ns)
                     elif name.endswith(DONE) and name[:-len(DONE)] in TRANSFERS:
@@ -205,7 +266,7 @@ def from_profile(pd) -> Trace:
                             (e.start_ns + e.duration_ns, chip))
     transfers = [(s, e, d, chip) for d in TRANSFERS.values()
                  for s, e, chip in _pair(starts[d], dones[d])]
-    return Trace(devices, dict(spans), transfers)
+    return Trace(devices, dict(spans), transfers, dict(runtime))
 
 
 def profile(trace_dir):
